@@ -24,6 +24,8 @@ def main(argv=None):
     ap.add_argument("--out-dir", default="runs/bench")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     n_sel = 10_000_000 if args.full else (100_000 if args.quick
                                           else 1_000_000)
     # join wall-clock on CPU is dominated by padded frontier compaction

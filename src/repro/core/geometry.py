@@ -82,6 +82,7 @@ def area(lx, ly, hx, hy):
 # ---------------------------------------------------------------------------
 
 _DELTA_CLAMP = np.float32(1.0e18)      # clamp²=1e36 < f32 max, still "huge"
+_SQ_CLAMP = np.float32(1.0e36)
 DIST_PAD = np.float32(3.0e38)          # distance slot for invalid lanes
 # d < this ⇔ lane held a real entry.  Must sit strictly between the largest
 # computable real distance (2·_DELTA_CLAMP² = 2e36) and DIST_PAD: invalid
@@ -89,6 +90,14 @@ DIST_PAD = np.float32(3.0e38)          # distance slot for invalid lanes
 # threshold only needs to separate those from genuine (possibly clamped)
 # distances.
 DIST_VALID_MAX = np.float32(1.0e37)
+
+
+def _sq(d):
+    """Square of a clamped axis gap.  The no-op ``minimum`` (d <= clamp, so
+    d*d <= clamp²) keeps the product out of FMA contraction: every caller's
+    sum of squares then rounds the same way in every fusion context (the
+    fused kernel bodies and their jnp twins stay bit-identical)."""
+    return jnp.minimum(d * d, _SQ_CLAMP)
 
 
 def _axis_gap(p, lo, hi):
@@ -101,9 +110,7 @@ def mindist(px, py, lx, ly, hx, hy):
     """Squared MINDIST(point, rect) (Roussopoulos & Kelley): 0 inside the
     rect, else squared distance to the nearest face/corner.  Broadcasts over
     array args; 2 gap stages + 2 fma — the D1-form SIMD sequence."""
-    dx = _axis_gap(px, lx, hx)
-    dy = _axis_gap(py, ly, hy)
-    return dx * dx + dy * dy
+    return _sq(_axis_gap(px, lx, hx)) + _sq(_axis_gap(py, ly, hy))
 
 
 def mindist_pairs(p, lo, hi):
@@ -137,7 +144,7 @@ def minmaxdist(px, py, lx, ly, hx, hy):
     dmy = jnp.minimum(jnp.abs(py - rmy), _DELTA_CLAMP)
     dMx = jnp.minimum(jnp.abs(px - rMx), _DELTA_CLAMP)
     dMy = jnp.minimum(jnp.abs(py - rMy), _DELTA_CLAMP)
-    return jnp.minimum(dmx * dmx + dMy * dMy, dmy * dmy + dMx * dMx)
+    return jnp.minimum(_sq(dmx) + _sq(dMy), _sq(dmy) + _sq(dMx))
 
 
 # ---------------------------------------------------------------------------
@@ -161,9 +168,8 @@ def mindist_rect(qlx, qly, qhx, qhy, lx, ly, hx, hy):
     """Squared MINDIST(rect, rect): 0 when the rects intersect, else the
     squared distance between their nearest faces/corners.  Broadcasts over
     array args; 2 gap stages + 2 fma — the D1-form SIMD sequence."""
-    dx = rect_axis_gap(qlx, qhx, lx, hx)
-    dy = rect_axis_gap(qly, qhy, ly, hy)
-    return dx * dx + dy * dy
+    return (_sq(rect_axis_gap(qlx, qhx, lx, hx))
+            + _sq(rect_axis_gap(qly, qhy, ly, hy)))
 
 
 def mindist_rect_pairs(q_lo, q_hi, lo, hi):
@@ -200,7 +206,7 @@ def minmaxdist_rect(qlx, qly, qhx, qhy, lx, ly, hx, hy):
     gyh = _face_gap(qly, qhy, hy)
     ngx, mgx = jnp.minimum(gxl, gxh), jnp.maximum(gxl, gxh)
     ngy, mgy = jnp.minimum(gyl, gyh), jnp.maximum(gyl, gyh)
-    return jnp.minimum(ngx * ngx + mgy * mgy, ngy * ngy + mgx * mgx)
+    return jnp.minimum(_sq(ngx) + _sq(mgy), _sq(ngy) + _sq(mgx))
 
 
 def mindist_np(px, py, lx, ly, hx, hy) -> np.ndarray:
@@ -266,14 +272,9 @@ def mindist_rect_matrix_np(rects_a, rects_b) -> np.ndarray:
                            b[None, :, 2], b[None, :, 3])
 
 
-def brute_force_knn(rects, points, k):
-    """Oracle: k nearest rects to each query point (numpy, O(B·N)).
-
-    rects: (N, 4); points: (B, 2) or (2,).  Returns (ids (B, k), sq-dists
-    (B, k)) sorted by distance (ties broken by id); rows are padded with
-    (-1, inf) when k > N.
-    """
-    d = mindist_matrix_np(points, rects)                     # (B, N)
+def _k_nearest(d: np.ndarray, k: int):
+    """Row-wise k smallest of a (B, N) distance matrix, sorted by distance
+    with ties broken by id; rows are padded with (-1, inf) when k > N."""
     b, n = d.shape
     kk = min(k, n)
     order = np.argsort(d, axis=1, kind="stable")[:, :kk]     # ties → low id
@@ -282,6 +283,16 @@ def brute_force_knn(rects, points, k):
     ids[:, :kk] = order
     out[:, :kk] = np.take_along_axis(d, order, axis=1)
     return ids, out
+
+
+def brute_force_knn(rects, points, k):
+    """Oracle: k nearest rects to each query point (numpy, O(B·N)).
+
+    rects: (N, 4); points: (B, 2) or (2,).  Returns (ids (B, k), sq-dists
+    (B, k)) sorted by distance (ties broken by id); rows are padded with
+    (-1, inf) when k > N.
+    """
+    return _k_nearest(mindist_matrix_np(points, rects), k)
 
 
 def brute_force_knn_join(outer_rects, inner_rects, k):
@@ -291,15 +302,7 @@ def brute_force_knn_join(outer_rects, inner_rects, k):
     sq-dists (B, k)) sorted by distance (ties broken by id); rows are padded
     with (-1, inf) when k > N.
     """
-    d = mindist_rect_matrix_np(outer_rects, inner_rects)     # (B, N)
-    b, n = d.shape
-    kk = min(k, n)
-    order = np.argsort(d, axis=1, kind="stable")[:, :kk]     # ties → low id
-    ids = np.full((b, k), -1, np.int64)
-    out = np.full((b, k), np.inf, np.float64)
-    ids[:, :kk] = order
-    out[:, :kk] = np.take_along_axis(d, order, axis=1)
-    return ids, out
+    return _k_nearest(mindist_rect_matrix_np(outer_rects, inner_rects), k)
 
 
 def brute_force_select(rects, query):

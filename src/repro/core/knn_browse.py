@@ -168,7 +168,6 @@ def make_sharded_browse(stacked_tree, ids_map, k: int, *, mesh,
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.geometry import DIST_PAD, DIST_VALID_MAX
@@ -256,12 +255,12 @@ def make_sharded_browse(stacked_tree, ids_map, k: int, *, mesh,
             states, overflow=states.overflow | crossed_g[None, :], ctr=ctr)
         return sel_ids, sel_d, states
 
-    init_prog = jax.jit(shard_map(
+    init_prog = jax.jit(jax.shard_map(
         _init_body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis),
-        check_rep=False))
-    step_prog = jax.jit(shard_map(
+        check_vma=False))
+    step_prog = jax.jit(jax.shard_map(
         _step_body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=(P(), P(), P(axis)), check_rep=False))
+        out_specs=(P(), P(), P(axis)), check_vma=False))
 
     def start(points) -> ShardedBrowseCursor:
         states = init_prog(stacked_tree, jnp.asarray(points))

@@ -540,7 +540,6 @@ def make_mesh_engine(name: str, stacked_tree, ids_map, *, mesh,
     Counters merge work fields across partitions and shards but keep
     ``dispatches``/``overflow`` as max (see collectives.psum_counters).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import collectives as coll
@@ -658,10 +657,10 @@ def make_mesh_engine(name: str, stacked_tree, ids_map, *, mesh,
     # leading partition axis
     tree_spec = P(axis)
     in_specs = (tree_spec, P(axis)) + ((P(),) if spec.query_width else ())
-    program = jax.jit(shard_map(body, mesh=mesh,
-                                in_specs=in_specs,
-                                out_specs=(P(), P(), P()),
-                                check_rep=False))
+    program = jax.jit(jax.shard_map(body, mesh=mesh,
+                                    in_specs=in_specs,
+                                    out_specs=(P(), P(), P()),
+                                    check_vma=False))
 
     def run(*qargs):
         return program(stacked_tree, ids_map, *qargs)

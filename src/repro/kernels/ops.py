@@ -1,11 +1,10 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-On the TPU target the kernels run compiled; on this CPU container they run
-in ``interpret=True`` mode (Python-evaluated kernel bodies) for correctness
-validation, while ``backend='xla'`` selects the pure-jnp reference path —
-identical math, XLA-fused — which the CPU benchmarks use so wall-clock
-numbers measure the algorithm rather than the interpreter.  The default
-('auto') picks pallas on TPU and xla elsewhere.
+Backends: ``'pallas'`` runs the kernels compiled for the TPU and refuses to
+run anywhere else; ``'pallas_interpret'`` evaluates the same kernel bodies
+in Pallas interpret mode (the CPU differential tests); ``'xla'`` selects
+the pure-jnp reference twins — identical math, XLA-fused.  ``'auto'``
+picks pallas on a TPU and xla elsewhere.
 
 Routing is one spec-keyed dispatch table (``_KERNELS``): every operator
 stage maps (spec name, stage) → (jnp reference twin, Pallas kernel), and
@@ -84,15 +83,34 @@ _KERNELS = {
 }
 
 
+# Which (layout, fused) cells each operator's kernel backends implement —
+# mirrors the engine guards: the level-global D1 SoA arrays have the full
+# kernel column, the quantized D3 streams have score kernels for
+# select/knn/knn_join plus the fused select variant, every other layout is
+# jnp-only (and knn_filtered has no kernel backend at all).
+KERNEL_CELLS = {
+    "select": {("d1", False), ("d1", True), ("d3", False), ("d3", True)},
+    "join": {("d1", False), ("d1", True)},
+    "knn": {("d1", False), ("d1", True), ("d3", False)},
+    "knn_join": {("d1", False), ("d1", True), ("d3", False)},
+    "knn_filtered": set(),
+}
+
+
 def kernel_call(op: str, stage: str, *args, backend: str = "auto", **kwargs):
-    """Dispatch one operator stage to its jnp twin (backend 'xla') or its
-    Pallas kernel (compiled on TPU, interpreted elsewhere)."""
+    """Dispatch one operator stage to its jnp twin (backend 'xla'), its
+    Pallas kernel compiled for the TPU ('pallas'), or the same kernel in
+    interpret mode ('pallas_interpret')."""
     ref_fn, pallas_fn = _KERNELS[(op, stage)]
     b = resolve_backend(backend)
     if b == "xla":
         return ref_fn(*args, **kwargs)
-    return pallas_fn(*args, interpret=(b == "pallas_interpret"
-                                       or not _on_tpu()), **kwargs)
+    if b == "pallas" and not _on_tpu():
+        raise RuntimeError(
+            f"backend='pallas' runs the {op}/{stage} kernel compiled for a "
+            f"TPU, but JAX's default backend is {jax.default_backend()!r}; "
+            f"use 'pallas_interpret' or 'xla' off the chip")
+    return pallas_fn(*args, interpret=b == "pallas_interpret", **kwargs)
 
 
 # ---------------------------------------------------------------------------

@@ -2,16 +2,16 @@
 
 Two generations of kernel live here:
 
-**Per-cell (unfused)** — ``knn_level_dists``: one grid step scores one
-(query, frontier-node) cell (squared MINDIST + squared MINMAXDIST of every
-child MBR against the query point) and hands the raw (B, C, F) distance
-tensors back to XLA for τ tightening, pruning, and beam compaction.  The
-frontier node ids ride the **scalar-prefetch operand**
+**Per-cell (unfused)** — ``knn_level_dists``: one grid step scores
+``chunk`` (query, frontier-node) cells (squared MINDIST + squared
+MINMAXDIST of every child MBR against the query point) and hands the raw
+(B, C, F) distance tensors back to XLA for τ tightening, pruning, and beam
+compaction.  The frontier node ids ride the **scalar-prefetch operand**
 (`PrefetchScalarGridSpec`) so node blocks are DMA'd HBM→VMEM one grid step
 ahead of the VPU math — the paper's software prefetching (O2) mapped onto
 the TPU DMA pipeline.  ``leaf=True`` selects the leaf-specialized variant
 (no MINMAXDIST math or store — the τ bound is never consumed below the
-leaves), ported from the pair-distance kernel (rtree_knn_join.py).
+leaves).
 
 **Whole-level (fused)** — ``knn_level_fused`` / ``knn_leaf_fused``: one
 ``pallas_call`` processes an *entire* BFS level.  The grid tiles over
@@ -19,12 +19,12 @@ leaves), ported from the pair-distance kernel (rtree_knn_join.py).
 block (``chunk`` frontier rows as parallel scalar-prefetched streams) and
 the emission stage runs *inside* the kernel:
 
-  pass 0 — running top-k of squared MINMAXDIST in VMEM scratch across the
-           frontier chunks; at the last chunk τ is tightened to the k-th
-           smallest (min with the carried-in τ) and written out.
+  pass 0 — running k smallest squared MINMAXDIST in VMEM scratch across
+           the frontier chunks; at the last chunk τ is tightened to the
+           k-th smallest (min with the carried-in τ) and written out.
   pass 1 — MINDIST ≤ τ pruning, then a running best-first beam (distance,
            child-id) of width ``cap`` merged chunk-by-chunk in VMEM scratch
-           (``lax.top_k`` on negated distances — a stable merge, so ties
+           (``fused_common.merge_smallest`` — a stable merge, so ties
            resolve exactly as one flat top-k over the level would); at the
            last chunk the compacted (cap,) frontier row and the per-query
            valid/keep tallies land in the outputs.
@@ -34,8 +34,7 @@ and two counter tallies per level — no (B, C, F) HBM intermediate and no
 per-level XLA round-trips (compare ``ref.knn_level_fused_ref``, the
 bit-compatible jnp twin).  The leaf kernel is the single-pass analogue that
 merges a running (distance, id) top-k of the *results* and never touches
-MINMAXDIST.  In-kernel ``top_k``/scatter validate under interpret mode;
-Mosaic lowering of those emission ops on real TPU is tracked in ROADMAP.
+MINMAXDIST.
 
 The generic machinery (`fused_inner_call` / `fused_leaf_call`) is shared
 with the rect-query kNN-join kernels, which pass their own distance
@@ -57,53 +56,55 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.geometry import DIST_PAD, DIST_VALID_MAX, mindist, minmaxdist
 from repro.core.layouts import d3_slacked_upper
 
-from .fused_common import chunk_tile as _chunk_tile
-from .fused_common import pad_frontier as _pad_frontier
+from . import fused_common as fc
 
 # Python floats: traced as literals, not captured consts, inside the kernels.
 _PAD = float(DIST_PAD)
 _VMAX = float(DIST_VALID_MAX)
 
 
-def _knn_kernel(ids_ref, p_ref, lx_ref, ly_ref, hx_ref, hy_ref, child_ref,
-                md_ref, mmd_ref):
-    # ids_ref (the scalar-prefetch operand) is consumed by the BlockSpec
-    # index maps, not the body
-    px = p_ref[0, 0]
-    py = p_ref[0, 1]
-    lx = lx_ref[0, :]
-    ly = ly_ref[0, :]
-    hx = hx_ref[0, :]
-    hy = hy_ref[0, :]
-    # the shared geometry formulas are pure jnp and trace inside the kernel
-    # body, so the kernel can never drift from the ref path it is
-    # parity-tested against
-    md = mindist(px, py, lx, ly, hx, hy)
-    mmd = minmaxdist(px, py, lx, ly, hx, hy)
-    # the prefetch operand carries clamped (non-negative) ids, so padded
-    # frontier slots are masked by the wrapper from the original ids' sign;
-    # in-kernel validity is child padding only
-    valid = child_ref[0, :] >= 0
-    md_ref[0, 0, :] = jnp.where(valid, md, _PAD)
-    mmd_ref[0, 0, :] = jnp.where(valid, mmd, _PAD)
+def dist_row_fn(md_fn, mmd_fn):
+    """Unfused D1 row scorer: (q, slot refs, ok) → (MINDIST[, MINMAXDIST])
+    rows with DIST_PAD on invalid lanes; ``mmd_fn=None`` is the leaf
+    variant (no MINMAXDIST math or store).  The shared geometry formulas
+    are pure jnp and trace inside the kernel body, so the kernel can never
+    drift from the ref path it is parity-tested against."""
+    def row(q, refs, ok):
+        lx, ly, hx, hy, child = (ref[...] for ref in refs)
+        valid = (child >= 0) & ok
+        out = (jnp.where(valid, md_fn(q, lx, ly, hx, hy), _PAD),)
+        if mmd_fn is not None:
+            out += (jnp.where(valid, mmd_fn(q, lx, ly, hx, hy), _PAD),)
+        return out
+    return row
 
 
-def _knn_leaf_kernel(ids_ref, p_ref, lx_ref, ly_ref, hx_ref, hy_ref,
-                     child_ref, md_ref):
-    # leaf-specialized: identical MINDIST sequence, no MINMAXDIST math or
-    # store — halves the kernel's output DMA on the largest frontier
-    # (ported from the pair-distance kernel, ROADMAP item)
-    px = p_ref[0, 0]
-    py = p_ref[0, 1]
-    md = mindist(px, py, lx_ref[0, :], ly_ref[0, :], hx_ref[0, :],
-                 hy_ref[0, :])
-    valid = child_ref[0, :] >= 0
-    md_ref[0, 0, :] = jnp.where(valid, md, _PAD)
+def d3_dist_row_fn(md_fn, mmd_fn):
+    """Unfused D3 row scorer: MINDIST on the dequantized (enlarged) boxes
+    (an admissible lower bound) and the slack-corrected MINMAXDIST
+    (core.layouts.d3_slacked_upper — a sound upper bound)."""
+    def row(q, refs, ok):
+        lx, ly, hx, hy = fc.d3_row_boxes(*refs[:4])
+        sl = refs[4]
+        disp = sl[0, 0] + sl[0, 1]
+        valid = (refs[5][...] >= 0) & ok
+        md = md_fn(q, lx, ly, hx, hy)
+        mmd = d3_slacked_upper(mmd_fn(q, lx, ly, hx, hy), disp)
+        return jnp.where(valid, md, _PAD), jnp.where(valid, mmd, _PAD)
+    return row
 
 
-@functools.partial(jax.jit, static_argnames=("leaf", "interpret"))
+def _point_md(q, lx, ly, hx, hy):
+    return mindist(q[0], q[1], lx, ly, hx, hy)
+
+
+def _point_mmd(q, lx, ly, hx, hy):
+    return minmaxdist(q[0], q[1], lx, ly, hx, hy)
+
+
+@functools.partial(jax.jit, static_argnames=("leaf", "chunk", "interpret"))
 def knn_level_dists(ids, points, lx, ly, hx, hy, child, *,
-                    leaf: bool = False, interpret: bool = True):
+                    leaf: bool = False, chunk: int = 8, interpret: bool):
     """Score one BFS level for a batch of kNN queries.
 
     ids:    (B, C) int32 frontier node ids (-1 pad) — scalar-prefetched.
@@ -114,81 +115,23 @@ def knn_level_dists(ids, points, lx, ly, hx, hy, child, *,
     invalid lanes; ``leaf=True`` selects the MINMAXDIST-free variant and
     returns None for the bound.
     """
-    b, c = ids.shape
-    n, f = lx.shape
-    safe_ids = jnp.maximum(ids, 0)
-
-    def node_map(bi, ci, ids_s):
-        return (ids_s[bi, ci], 0)
-
-    out_spec = pl.BlockSpec((1, 1, f), lambda bi, ci, ids_s: (bi, ci, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, c),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda bi, ci, ids_s: (bi, 0)),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-        ],
-        out_specs=[out_spec] if leaf else [out_spec, out_spec],
-    )
-    shape = jax.ShapeDtypeStruct((b, c, f), jnp.float32)
-    fn = pl.pallas_call(
-        _knn_leaf_kernel if leaf else _knn_kernel,
-        grid_spec=grid_spec,
-        out_shape=[shape] if leaf else [shape, shape],
-        interpret=interpret,
-    )
-    # Original ids enter the kernel for the validity sign test; safe ids drive
-    # the index maps so padding never DMAs out of bounds.  The ids used for
-    # indexing are the prefetch operand, so pass safe ids there and recover
-    # validity from the broadcasted original sign afterwards.
-    out = fn(safe_ids, points, lx, ly, hx, hy, child)
-    invalid = (ids < 0)[:, :, None]
-    if leaf:
-        return jnp.where(invalid, _PAD, out[0]), None
-    return (jnp.where(invalid, _PAD, out[0]),
-            jnp.where(invalid, _PAD, out[1]))
+    out = fc.score_rows(
+        dist_row_fn(_point_md, None if leaf else _point_mmd), ids, points,
+        [lx, ly, hx, hy, child], n_out=1 if leaf else 2,
+        out_dtype=jnp.float32, chunk=chunk, interpret=interpret)
+    return (out[0], None) if leaf else tuple(out)
 
 
 # ---------------------------------------------------------------------------
 # D3 quantized-layout kernel: the node block streams two packed-uint16 code
 # rows (4 bytes per child MBR — ~4x the children per DMA'd block) plus the
-# (1, 2) scale/bias/slack rows; boxes are dequantized in-register.  MINDIST
-# on the conservatively enlarged boxes is an admissible lower bound;
-# MINMAXDIST goes through the stored-slack Lipschitz correction
-# (core.layouts.d3_slacked_upper) to stay a sound upper bound.  Internal
+# (1, 2) scale/bias/slack rows; boxes are dequantized in-register.  Internal
 # levels only — the operators route leaf rows through the exact D1 kernel.
 # ---------------------------------------------------------------------------
 
-def _knn_d3_kernel(ids_ref, p_ref, qlo_ref, qhi_ref, sc_ref, bi_ref, sl_ref,
-                   ptr_ref, md_ref, mmd_ref):
-    px = p_ref[0, 0]
-    py = p_ref[0, 1]
-    qlo = qlo_ref[0, :].astype(jnp.int32)
-    qhi = qhi_ref[0, :].astype(jnp.int32)
-    sx, sy = sc_ref[0, 0], sc_ref[0, 1]
-    bx, by = bi_ref[0, 0], bi_ref[0, 1]
-    # exact dequantization (8-bit codes x pow2 scale) — bitwise identical to
-    # the jnp layout path, so kernel and ref twin can never drift
-    lx = bx + (qlo >> 8).astype(jnp.float32) * sx
-    ly = by + (qlo & 0xFF).astype(jnp.float32) * sy
-    hx = bx + (qhi >> 8).astype(jnp.float32) * sx
-    hy = by + (qhi & 0xFF).astype(jnp.float32) * sy
-    md = mindist(px, py, lx, ly, hx, hy)
-    disp = sl_ref[0, 0] + sl_ref[0, 1]
-    mmd = d3_slacked_upper(minmaxdist(px, py, lx, ly, hx, hy), disp)
-    valid = ptr_ref[0, :] >= 0
-    md_ref[0, 0, :] = jnp.where(valid, md, _PAD)
-    mmd_ref[0, 0, :] = jnp.where(valid, mmd, _PAD)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def knn_level_dists_d3(ids, points, qlo, qhi, scale, bias, slack, ptr, *,
-                       interpret: bool = True):
+                       chunk: int = 8, interpret: bool):
     """Score one quantized BFS level for a batch of kNN queries.
 
     ids:     (B, C) int32 frontier node ids (-1 pad) — scalar-prefetched.
@@ -199,39 +142,10 @@ def knn_level_dists_d3(ids, points, qlo, qhi, scale, bias, slack, ptr, *,
     → (mindist (B, C, F) lower bound, slacked minmaxdist (B, C, F) upper
     bound) f32, DIST_PAD on invalid lanes.
     """
-    b, c = ids.shape
-    n, f = qlo.shape
-    safe_ids = jnp.maximum(ids, 0)
-
-    def node_map(bi, ci, ids_s):
-        return (ids_s[bi, ci], 0)
-
-    out_spec = pl.BlockSpec((1, 1, f), lambda bi, ci, ids_s: (bi, ci, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, c),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda bi, ci, ids_s: (bi, 0)),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, 2), node_map),
-            pl.BlockSpec((1, 2), node_map),
-            pl.BlockSpec((1, 2), node_map),
-            pl.BlockSpec((1, f), node_map),
-        ],
-        out_specs=[out_spec, out_spec],
-    )
-    shape = jax.ShapeDtypeStruct((b, c, f), jnp.float32)
-    fn = pl.pallas_call(
-        _knn_d3_kernel,
-        grid_spec=grid_spec,
-        out_shape=[shape, shape],
-        interpret=interpret,
-    )
-    out = fn(safe_ids, points, qlo, qhi, scale, bias, slack, ptr)
-    invalid = (ids < 0)[:, :, None]
-    return (jnp.where(invalid, _PAD, out[0]),
-            jnp.where(invalid, _PAD, out[1]))
+    return tuple(fc.score_rows(
+        d3_dist_row_fn(_point_md, _point_mmd), ids, points,
+        [qlo, qhi, scale, bias, slack, ptr], n_out=2,
+        out_dtype=jnp.float32, chunk=chunk, interpret=interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -247,115 +161,115 @@ def fused_inner_call(ids, queries, lx, ly, hx, hy, child, tau, *,
     valid_cnt (B,), keep_cnt (B,)) — the bit-compatible fusion of
     score → τ top-k → prune → beam_rows (see ref.knn_level_fused_ref).
     """
-    b, _ = ids.shape
-    n, f = lx.shape
     qw = queries.shape[1]
-    ids, r, nc = _pad_frontier(ids, chunk)
-    safe = jnp.maximum(ids, 0)
+    ids, r, nc = fc.pad_frontier(ids, chunk)
+    cp = r * nc
+    arrays = [lx, ly, hx, hy, child]
+    # τ rides the per-query SMEM operand as one more query column
+    qt = jnp.concatenate([queries.astype(jnp.float32),
+                          tau.astype(jnp.float32)[:, None]], axis=1)
 
-    def kernel(safe_ref, raw_ref, q_ref, *rest):
-        node_refs = rest[:5 * r]
-        tau_in_ref = rest[5 * r]
-        out_ref, tau_out_ref, stats_ref = rest[5 * r + 1:5 * r + 4]
-        topk_ref, beam_d_ref, beam_v_ref, cnt_sm, tau_sm = rest[5 * r + 4:]
-        bi = pl.program_id(0)
-        ps = pl.program_id(1)
-        ci = pl.program_id(2)
-        last = ci == nc - 1
+    def call(ids_g, q_g):
+        g = ids_g.shape[0]
 
-        @pl.when((ps == 0) & (ci == 0))
-        def _():
-            tau_sm[0] = tau_in_ref[0, 0]
-            cnt_sm[0] = 0
-            cnt_sm[1] = 0
-            topk_ref[0, :] = jnp.full((k,), _PAD, jnp.float32)
-            beam_d_ref[0, :] = jnp.full((cap,), _PAD, jnp.float32)
-            beam_v_ref[0, :] = jnp.full((cap,), -1, jnp.int32)
+        def kernel(ids_ref, q_ref, *rest):
+            row_refs = rest[:5 * r]
+            out_ref, tau_ref, stats_ref = rest[5 * r:5 * r + 3]
+            topk_ref, beam_d_ref, beam_v_ref, cnt_sm, tau_sm = rest[5 * r + 3:]
+            bi = pl.program_id(0)
+            ps = pl.program_id(1)
+            ci = pl.program_id(2)
+            last = ci == nc - 1
 
-        glx, gly, ghx, ghy, child_t, valid = _chunk_tile(
-            raw_ref, node_refs, bi, ci, r)
-        q = tuple(q_ref[0, i] for i in range(qw))
+            @pl.when((ps == 0) & (ci == 0))
+            def _():
+                tau_sm[0] = q_ref[bi * (qw + 1) + qw]
+                cnt_sm[0] = 0
+                cnt_sm[1] = 0
+                topk_ref[...] = jnp.full((1, k), _PAD, jnp.float32)
+                beam_d_ref[...] = jnp.full((1, cap), _PAD, jnp.float32)
+                beam_v_ref[...] = jnp.full((1, cap), -1, jnp.int32)
 
-        @pl.when(ps == 0)
-        def _():
-            # τ pass: running top-k of the MINMAXDIST bound.  The set of k
-            # smallest values is chunk-order invariant, so the k-th value is
-            # bitwise the one a flat top-k over the level would produce.
-            if tighten:
-                mmd = jnp.where(valid, mmd_fn(q, glx, gly, ghx, ghy), _PAD)
-                cand = jnp.concatenate([topk_ref[0, :], mmd.reshape(-1)])
-                topk_ref[0, :] = -jax.lax.top_k(-cand, k)[0]
+            glx, gly, ghx, ghy, child_t = fc.chunk_tile(row_refs, r)
+            valid = (child_t >= 0) & fc.slot_ok(ids_ref, bi * cp + ci * r, r)
+            q = fc.query_scalars(q_ref, bi, qw + 1)[:qw]
+
+            @pl.when(ps == 0)
+            def _():
+                # τ pass: running k smallest of the MINMAXDIST bound.  The
+                # set of k smallest values is chunk-order invariant, so the
+                # k-th value is bitwise the one a flat top-k would produce.
+                if tighten:
+                    mmd = jnp.where(valid, mmd_fn(q, glx, gly, ghx, ghy),
+                                    _PAD)
+                    d, _ = fc.merge_smallest(
+                        topk_ref[...], jnp.zeros((1, k), jnp.int32), mmd,
+                        jnp.zeros(mmd.shape, jnp.int32), k)
+                    topk_ref[...] = d
+
+                    @pl.when(last)
+                    def _():
+                        tau_sm[0] = jnp.minimum(tau_sm[0],
+                                                jnp.max(topk_ref[...]))
 
                 @pl.when(last)
                 def _():
-                    tau_sm[0] = jnp.minimum(tau_sm[0], topk_ref[0, k - 1])
+                    tau_ref[bi] = tau_sm[0]
 
-            @pl.when(last)
+            @pl.when(ps == 1)
             def _():
-                tau_out_ref[0, 0] = tau_sm[0]
+                # emit pass: MINDIST ≤ τ prune, then stable best-first beam
+                # merge — kept entries precede the new chunk, so ties
+                # resolve exactly as the flat beam_rows order.
+                md = jnp.where(valid, md_fn(q, glx, gly, ghx, ghy), _PAD)
+                keep = valid & (md <= tau_sm[0])
+                cnt_sm[0] = cnt_sm[0] + jnp.sum(valid.astype(jnp.int32))
+                cnt_sm[1] = cnt_sm[1] + jnp.sum(keep.astype(jnp.int32))
+                d, v = fc.merge_smallest(
+                    beam_d_ref[...], beam_v_ref[...],
+                    jnp.where(keep, md, _PAD), jnp.where(keep, child_t, -1),
+                    cap)
+                beam_d_ref[...] = d
+                beam_v_ref[...] = v
 
-        @pl.when(ps == 1)
-        def _():
-            # emit pass: MINDIST ≤ τ prune, then stable best-first beam
-            # merge — previously-kept entries precede the new chunk in the
-            # concat, so lax.top_k's lowest-index tie-breaking reproduces
-            # the flat beam_rows order exactly.
-            md = jnp.where(valid, md_fn(q, glx, gly, ghx, ghy), _PAD)
-            keep = valid & (md <= tau_sm[0])
-            cnt_sm[0] = cnt_sm[0] + valid.sum().astype(jnp.int32)
-            cnt_sm[1] = cnt_sm[1] + keep.sum().astype(jnp.int32)
-            cd = jnp.concatenate([beam_d_ref[0, :],
-                                  jnp.where(keep, md, _PAD).reshape(-1)])
-            cv = jnp.concatenate([beam_v_ref[0, :],
-                                  jnp.where(keep, child_t, -1).reshape(-1)])
-            neg, pos = jax.lax.top_k(-cd, cap)
-            beam_d_ref[0, :] = -neg
-            beam_v_ref[0, :] = jnp.take_along_axis(cv, pos, axis=0)
+                @pl.when(last)
+                def _():
+                    found = beam_d_ref[...] < _VMAX
+                    out_ref[...] = jnp.where(found, beam_v_ref[...], -1)
+                    stats_ref[2 * bi] = cnt_sm[0]
+                    stats_ref[2 * bi + 1] = cnt_sm[1]
 
-            @pl.when(last)
-            def _():
-                found = beam_d_ref[0, :] < _VMAX
-                out_ref[0, :] = jnp.where(found, beam_v_ref[0, :], -1)
-                stats_ref[0, 0] = cnt_sm[0]
-                stats_ref[0, 1] = cnt_sm[1]
+        def slot(i, bi, ps, ci, s, qs):
+            return s[bi * cp + ci * r + i]
 
-    def bmap(bi, ps, ci, s, rw):
-        return (bi, 0)
+        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(g, 2, nc),
+            in_specs=fc.row_specs(arrays, r, slot),
+            out_specs=[pl.BlockSpec((None, 1, cap),
+                                    lambda bi, ps, ci, s, qs: (bi, 0, 0)),
+                       smem, smem],
+            scratch_shapes=[
+                pltpu.VMEM((1, k), jnp.float32),      # running MINMAXDIST top-k
+                pltpu.VMEM((1, cap), jnp.float32),    # beam distances
+                pltpu.VMEM((1, cap), jnp.int32),      # beam child ids
+                pltpu.SMEM((2,), jnp.int32),          # valid / keep tallies
+                pltpu.SMEM((1,), jnp.float32),        # τ carried across passes
+            ],
+        )
+        out, tau_out, stats = pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((g, 1, cap), jnp.int32),
+                       jax.ShapeDtypeStruct((g,), jnp.float32),
+                       jax.ShapeDtypeStruct((2 * g,), jnp.int32)],
+            interpret=interpret,
+        )(ids_g.reshape(-1), q_g.reshape(-1), *fc.row_operands(arrays, r))
+        return out[:, 0], tau_out, stats.reshape(g, 2)
 
-    in_specs = [pl.BlockSpec((1, qw), bmap)]
-    for i in range(r):
-        def node_map(bi, ps, ci, s, rw, i=i):
-            return (s[bi, ci * r + i], 0)
-        in_specs += [pl.BlockSpec((1, f), node_map)] * 5
-    in_specs.append(pl.BlockSpec((1, 1), bmap))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, 2, nc),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, cap), bmap),
-                   pl.BlockSpec((1, 1), bmap),
-                   pl.BlockSpec((1, 2), bmap)],
-        scratch_shapes=[
-            pltpu.VMEM((1, k), jnp.float32),      # running MINMAXDIST top-k
-            pltpu.VMEM((1, cap), jnp.float32),    # beam distances
-            pltpu.VMEM((1, cap), jnp.int32),      # beam child ids
-            pltpu.SMEM((2,), jnp.int32),          # valid / keep tallies
-            pltpu.SMEM((1,), jnp.float32),        # τ carried across passes
-        ],
-    )
-    fn = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, cap), jnp.int32),
-                   jax.ShapeDtypeStruct((b, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((b, 2), jnp.int32)],
-        interpret=interpret,
-    )
-    operands = [queries] + [lx, ly, hx, hy, child] * r + \
-        [tau.reshape(b, 1).astype(jnp.float32)]
-    out_ids, tau_out, stats = fn(safe, ids, *operands)
-    return out_ids, tau_out[:, 0], stats[:, 0], stats[:, 1]
+    out_ids, tau_out, stats = fc.by_groups(call, [ids, qt], [-1, 0],
+                                            cp + qw + 1)
+    return out_ids, tau_out, stats[:, 0], stats[:, 1]
 
 
 def fused_leaf_call(ids, queries, lx, ly, hx, hy, child, *, k: int,
@@ -365,87 +279,77 @@ def fused_leaf_call(ids, queries, lx, ly, hx, hy, child, *, k: int,
     leaf never consumes the MINMAXDIST bound, so the specialization is
     structural here, not a variant flag).  Returns (res_ids (B, k),
     res_d (B, k), valid_cnt (B,)); missing neighbours are (-1, +inf)."""
-    b, _ = ids.shape
-    n, f = lx.shape
     qw = queries.shape[1]
-    ids, r, nc = _pad_frontier(ids, chunk)
-    safe = jnp.maximum(ids, 0)
+    ids, r, nc = fc.pad_frontier(ids, chunk)
+    cp = r * nc
+    arrays = [lx, ly, hx, hy, child]
+    queries = queries.astype(jnp.float32)
 
-    def kernel(safe_ref, raw_ref, q_ref, *rest):
-        node_refs = rest[:5 * r]
-        ids_ref, d_ref, stats_ref = rest[5 * r:5 * r + 3]
-        beam_d_ref, beam_v_ref, cnt_sm = rest[5 * r + 3:]
-        bi = pl.program_id(0)
-        ci = pl.program_id(1)
+    def call(ids_g, q_g):
+        g = ids_g.shape[0]
 
-        @pl.when(ci == 0)
-        def _():
-            cnt_sm[0] = 0
-            beam_d_ref[0, :] = jnp.full((k,), _PAD, jnp.float32)
-            beam_v_ref[0, :] = jnp.full((k,), -1, jnp.int32)
+        def kernel(ids_ref, q_ref, *rest):
+            row_refs = rest[:5 * r]
+            ids_out, d_out, stats_ref = rest[5 * r:5 * r + 3]
+            beam_d_ref, beam_v_ref, cnt_sm = rest[5 * r + 3:]
+            bi = pl.program_id(0)
+            ci = pl.program_id(1)
 
-        glx, gly, ghx, ghy, child_t, valid = _chunk_tile(
-            raw_ref, node_refs, bi, ci, r)
-        q = tuple(q_ref[0, i] for i in range(qw))
-        md = jnp.where(valid, md_fn(q, glx, gly, ghx, ghy), _PAD)
-        cnt_sm[0] = cnt_sm[0] + valid.sum().astype(jnp.int32)
-        # result ids ride unmasked (as in the flat top-k twin): any entry
-        # still at DIST_PAD is masked to (-1, inf) at the end, so invalid
-        # lanes can never surface a qualifying id
-        cd = jnp.concatenate([beam_d_ref[0, :], md.reshape(-1)])
-        cv = jnp.concatenate([beam_v_ref[0, :], child_t.reshape(-1)])
-        neg, pos = jax.lax.top_k(-cd, k)
-        beam_d_ref[0, :] = -neg
-        beam_v_ref[0, :] = jnp.take_along_axis(cv, pos, axis=0)
+            @pl.when(ci == 0)
+            def _():
+                cnt_sm[0] = 0
+                beam_d_ref[...] = jnp.full((1, k), _PAD, jnp.float32)
+                beam_v_ref[...] = jnp.full((1, k), -1, jnp.int32)
 
-        @pl.when(ci == nc - 1)
-        def _():
-            found = beam_d_ref[0, :] < _VMAX
-            ids_ref[0, :] = jnp.where(found, beam_v_ref[0, :], -1)
-            d_ref[0, :] = jnp.where(found, beam_d_ref[0, :], jnp.inf)
-            stats_ref[0, 0] = cnt_sm[0]
+            glx, gly, ghx, ghy, child_t = fc.chunk_tile(row_refs, r)
+            valid = (child_t >= 0) & fc.slot_ok(ids_ref, bi * cp + ci * r, r)
+            q = fc.query_scalars(q_ref, bi, qw)
+            md = jnp.where(valid, md_fn(q, glx, gly, ghx, ghy), _PAD)
+            cnt_sm[0] = cnt_sm[0] + jnp.sum(valid.astype(jnp.int32))
+            # result ids ride unmasked (as in the flat top-k twin): any entry
+            # still at DIST_PAD is masked to (-1, inf) at the end, so invalid
+            # lanes can never surface a qualifying id
+            d, v = fc.merge_smallest(beam_d_ref[...], beam_v_ref[...], md,
+                                     child_t, k)
+            beam_d_ref[...] = d
+            beam_v_ref[...] = v
 
-    def bmap(bi, ci, s, rw):
-        return (bi, 0)
+            @pl.when(ci == nc - 1)
+            def _():
+                found = beam_d_ref[...] < _VMAX
+                ids_out[...] = jnp.where(found, beam_v_ref[...], -1)
+                d_out[...] = jnp.where(found, beam_d_ref[...], jnp.inf)
+                stats_ref[bi] = cnt_sm[0]
 
-    in_specs = [pl.BlockSpec((1, qw), bmap)]
-    for i in range(r):
-        def node_map(bi, ci, s, rw, i=i):
-            return (s[bi, ci * r + i], 0)
-        in_specs += [pl.BlockSpec((1, f), node_map)] * 5
+        def slot(i, bi, ci, s, qs):
+            return s[bi * cp + ci * r + i]
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nc),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, k), bmap),
-                   pl.BlockSpec((1, k), bmap),
-                   pl.BlockSpec((1, 1), bmap)],
-        scratch_shapes=[
-            pltpu.VMEM((1, k), jnp.float32),      # result beam distances
-            pltpu.VMEM((1, k), jnp.int32),        # result beam ids
-            pltpu.SMEM((1,), jnp.int32),          # valid tally
-        ],
-    )
-    fn = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, k), jnp.int32),
-                   jax.ShapeDtypeStruct((b, k), jnp.float32),
-                   jax.ShapeDtypeStruct((b, 1), jnp.int32)],
-        interpret=interpret,
-    )
-    out_ids, out_d, stats = fn(safe, ids, *([queries] +
-                                            [lx, ly, hx, hy, child] * r))
-    return out_ids, out_d, stats[:, 0]
+        def row_out(bi, ci, s, qs):
+            return (bi, 0, 0)
 
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(g, nc),
+            in_specs=fc.row_specs(arrays, r, slot),
+            out_specs=[pl.BlockSpec((None, 1, k), row_out),
+                       pl.BlockSpec((None, 1, k), row_out),
+                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[
+                pltpu.VMEM((1, k), jnp.float32),      # result beam distances
+                pltpu.VMEM((1, k), jnp.int32),        # result beam ids
+                pltpu.SMEM((1,), jnp.int32),          # valid tally
+            ],
+        )
+        out_ids, out_d, stats = pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((g, 1, k), jnp.int32),
+                       jax.ShapeDtypeStruct((g, 1, k), jnp.float32),
+                       jax.ShapeDtypeStruct((g,), jnp.int32)],
+            interpret=interpret,
+        )(ids_g.reshape(-1), q_g.reshape(-1), *fc.row_operands(arrays, r))
+        return out_ids[:, 0], out_d[:, 0], stats
 
-def _point_md(q, lx, ly, hx, hy):
-    return mindist(q[0], q[1], lx, ly, hx, hy)
-
-
-def _point_mmd(q, lx, ly, hx, hy):
-    return minmaxdist(q[0], q[1], lx, ly, hx, hy)
+    return fc.by_groups(call, [ids, queries], [-1, 0], cp + qw)
 
 
 @functools.partial(jax.jit,
@@ -453,7 +357,7 @@ def _point_mmd(q, lx, ly, hx, hy):
                                     "interpret"))
 def knn_level_fused(ids, points, lx, ly, hx, hy, child, tau, *, cap: int,
                     k: int, tighten: bool, chunk: int = 8,
-                    interpret: bool = True):
+                    interpret: bool):
     """Fused internal-level step for point kNN: (B, C) frontier → compacted
     (B, cap) next frontier + tightened τ + valid/keep tallies, one
     pallas_call (see module docstring)."""
@@ -465,7 +369,7 @@ def knn_level_fused(ids, points, lx, ly, hx, hy, child, tau, *, cap: int,
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk", "interpret"))
 def knn_leaf_fused(ids, points, lx, ly, hx, hy, child, *, k: int,
-                   chunk: int = 8, interpret: bool = True):
+                   chunk: int = 8, interpret: bool):
     """Fused leaf-level step for point kNN: (B, C) leaf frontier → the k
     best (id, squared distance) per query, one pallas_call."""
     return fused_leaf_call(ids, points, lx, ly, hx, hy, child, k=k,

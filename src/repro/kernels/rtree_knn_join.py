@@ -1,13 +1,13 @@
 """Pallas TPU kernel: R-tree kNN-join BFS level step (pair distances).
 
 The kNN distance kernel (rtree_knn.py) generalized to rect queries: one grid
-step scores one (outer rect, frontier-node) cell — squared rect-to-rect
-MINDIST and rect MINMAXDIST of every child MBR of the inner node against the
-outer query rect.  Frontier node ids ride the scalar-prefetch operand
+step scores ``chunk`` (outer rect, frontier-node) cells — squared
+rect-to-rect MINDIST and rect MINMAXDIST of every child MBR of the inner
+node against the outer query rect.  Frontier node ids ride the scalar-prefetch operand
 (`PrefetchScalarGridSpec`) exactly as in the select/kNN kernels, so node
 blocks are DMA'd HBM→VMEM one grid step ahead of the VPU math.
 
-Two variants share the scoring sequence:
+Two variants share the scoring sequence (``rtree_knn.dist_row_fn``):
 
   generic — MINDIST + MINMAXDIST from one DMA of the four key-excerpt rows
             (internal levels: the τ bound consumes MINMAXDIST).
@@ -32,60 +32,26 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.geometry import DIST_PAD, mindist_rect, minmaxdist_rect
-from repro.core.layouts import d3_slacked_upper
+from repro.core.geometry import mindist_rect, minmaxdist_rect
 
-from .rtree_knn import fused_inner_call, fused_leaf_call
-
-# Python float: traced as a literal, not a captured const, inside the kernel.
-_PAD = float(DIST_PAD)
+from . import fused_common as fc
+from .rtree_knn import (d3_dist_row_fn, dist_row_fn, fused_inner_call,
+                        fused_leaf_call)
 
 
-def _knn_join_kernel(ids_ref, q_ref, lx_ref, ly_ref, hx_ref, hy_ref,
-                     child_ref, md_ref, mmd_ref):
-    # ids_ref (the scalar-prefetch operand) is consumed by the BlockSpec
-    # index maps, not the body
-    qlx = q_ref[0, 0]
-    qly = q_ref[0, 1]
-    qhx = q_ref[0, 2]
-    qhy = q_ref[0, 3]
-    lx = lx_ref[0, :]
-    ly = ly_ref[0, :]
-    hx = hx_ref[0, :]
-    hy = hy_ref[0, :]
-    # the shared geometry formulas are pure jnp and trace inside the kernel
-    # body, so the kernel can never drift from the ref path it is
-    # parity-tested against
-    md = mindist_rect(qlx, qly, qhx, qhy, lx, ly, hx, hy)
-    mmd = minmaxdist_rect(qlx, qly, qhx, qhy, lx, ly, hx, hy)
-    valid = child_ref[0, :] >= 0
-    md_ref[0, 0, :] = jnp.where(valid, md, _PAD)
-    mmd_ref[0, 0, :] = jnp.where(valid, mmd, _PAD)
+def _rect_md(q, lx, ly, hx, hy):
+    return mindist_rect(q[0], q[1], q[2], q[3], lx, ly, hx, hy)
 
 
-def _knn_join_leaf_kernel(ids_ref, q_ref, lx_ref, ly_ref, hx_ref, hy_ref,
-                          child_ref, md_ref):
-    # leaf-specialized: identical MINDIST sequence, no MINMAXDIST math or
-    # store — halves the kernel's output DMA on the largest frontier
-    qlx = q_ref[0, 0]
-    qly = q_ref[0, 1]
-    qhx = q_ref[0, 2]
-    qhy = q_ref[0, 3]
-    lx = lx_ref[0, :]
-    ly = ly_ref[0, :]
-    hx = hx_ref[0, :]
-    hy = hy_ref[0, :]
-    md = mindist_rect(qlx, qly, qhx, qhy, lx, ly, hx, hy)
-    valid = child_ref[0, :] >= 0
-    md_ref[0, 0, :] = jnp.where(valid, md, _PAD)
+def _rect_mmd(q, lx, ly, hx, hy):
+    return minmaxdist_rect(q[0], q[1], q[2], q[3], lx, ly, hx, hy)
 
 
-@functools.partial(jax.jit, static_argnames=("leaf", "interpret"))
+@functools.partial(jax.jit, static_argnames=("leaf", "chunk", "interpret"))
 def knn_join_level_dists(ids, qrects, lx, ly, hx, hy, child, *,
-                         leaf: bool = False, interpret: bool = True):
+                         leaf: bool = False, chunk: int = 8,
+                         interpret: bool):
     """Score one BFS level for a batch of kNN-join outer rects.
 
     ids:    (B, C) int32 frontier node ids (-1 pad) — scalar-prefetched.
@@ -96,42 +62,11 @@ def knn_join_level_dists(ids, qrects, lx, ly, hx, hy, child, *,
     invalid lanes; ``leaf=True`` selects the MINMAXDIST-free variant and
     returns None for the bound.
     """
-    b, c = ids.shape
-    n, f = lx.shape
-    safe_ids = jnp.maximum(ids, 0)
-
-    def node_map(bi, ci, ids_s):
-        return (ids_s[bi, ci], 0)
-
-    out_spec = pl.BlockSpec((1, 1, f), lambda bi, ci, ids_s: (bi, ci, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, c),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda bi, ci, ids_s: (bi, 0)),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-        ],
-        out_specs=[out_spec] if leaf else [out_spec, out_spec],
-    )
-    shape = jax.ShapeDtypeStruct((b, c, f), jnp.float32)
-    fn = pl.pallas_call(
-        _knn_join_leaf_kernel if leaf else _knn_join_kernel,
-        grid_spec=grid_spec,
-        out_shape=[shape] if leaf else [shape, shape],
-        interpret=interpret,
-    )
-    # Safe ids drive the index maps so padding never DMAs out of bounds;
-    # validity is recovered from the original ids' sign afterwards.
-    out = fn(safe_ids, qrects, lx, ly, hx, hy, child)
-    invalid = (ids < 0)[:, :, None]
-    if leaf:
-        return jnp.where(invalid, _PAD, out[0]), None
-    return (jnp.where(invalid, _PAD, out[0]),
-            jnp.where(invalid, _PAD, out[1]))
+    out = fc.score_rows(
+        dist_row_fn(_rect_md, None if leaf else _rect_mmd), ids, qrects,
+        [lx, ly, hx, hy, child], n_out=1 if leaf else 2,
+        out_dtype=jnp.float32, chunk=chunk, interpret=interpret)
+    return (out[0], None) if leaf else tuple(out)
 
 
 # ---------------------------------------------------------------------------
@@ -141,68 +76,16 @@ def knn_join_level_dists(ids, qrects, lx, ly, hx, hy, child, *,
 # D1 kernel)
 # ---------------------------------------------------------------------------
 
-def _knn_join_d3_kernel(ids_ref, q_ref, qlo_ref, qhi_ref, sc_ref, bi_ref,
-                        sl_ref, ptr_ref, md_ref, mmd_ref):
-    qlx = q_ref[0, 0]
-    qly = q_ref[0, 1]
-    qhx = q_ref[0, 2]
-    qhy = q_ref[0, 3]
-    qlo = qlo_ref[0, :].astype(jnp.int32)
-    qhi = qhi_ref[0, :].astype(jnp.int32)
-    sx, sy = sc_ref[0, 0], sc_ref[0, 1]
-    bx, by = bi_ref[0, 0], bi_ref[0, 1]
-    lx = bx + (qlo >> 8).astype(jnp.float32) * sx
-    ly = by + (qlo & 0xFF).astype(jnp.float32) * sy
-    hx = bx + (qhi >> 8).astype(jnp.float32) * sx
-    hy = by + (qhi & 0xFF).astype(jnp.float32) * sy
-    md = mindist_rect(qlx, qly, qhx, qhy, lx, ly, hx, hy)
-    disp = sl_ref[0, 0] + sl_ref[0, 1]
-    mmd = d3_slacked_upper(
-        minmaxdist_rect(qlx, qly, qhx, qhy, lx, ly, hx, hy), disp)
-    valid = ptr_ref[0, :] >= 0
-    md_ref[0, 0, :] = jnp.where(valid, md, _PAD)
-    mmd_ref[0, 0, :] = jnp.where(valid, mmd, _PAD)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def knn_join_level_dists_d3(ids, qrects, qlo, qhi, scale, bias, slack, ptr,
-                            *, interpret: bool = True):
+                            *, chunk: int = 8, interpret: bool):
     """Score one quantized BFS level for a batch of kNN-join outer rects —
     contract as ``knn_level_dists_d3`` with rect queries: (admissible
     MINDIST lower bound, slack-corrected MINMAXDIST upper bound)."""
-    b, c = ids.shape
-    n, f = qlo.shape
-    safe_ids = jnp.maximum(ids, 0)
-
-    def node_map(bi, ci, ids_s):
-        return (ids_s[bi, ci], 0)
-
-    out_spec = pl.BlockSpec((1, 1, f), lambda bi, ci, ids_s: (bi, ci, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, c),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda bi, ci, ids_s: (bi, 0)),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, 2), node_map),
-            pl.BlockSpec((1, 2), node_map),
-            pl.BlockSpec((1, 2), node_map),
-            pl.BlockSpec((1, f), node_map),
-        ],
-        out_specs=[out_spec, out_spec],
-    )
-    shape = jax.ShapeDtypeStruct((b, c, f), jnp.float32)
-    fn = pl.pallas_call(
-        _knn_join_d3_kernel,
-        grid_spec=grid_spec,
-        out_shape=[shape, shape],
-        interpret=interpret,
-    )
-    out = fn(safe_ids, qrects, qlo, qhi, scale, bias, slack, ptr)
-    invalid = (ids < 0)[:, :, None]
-    return (jnp.where(invalid, _PAD, out[0]),
-            jnp.where(invalid, _PAD, out[1]))
+    return tuple(fc.score_rows(
+        d3_dist_row_fn(_rect_md, _rect_mmd), ids, qrects,
+        [qlo, qhi, scale, bias, slack, ptr], n_out=2,
+        out_dtype=jnp.float32, chunk=chunk, interpret=interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -210,20 +93,12 @@ def knn_join_level_dists_d3(ids, qrects, qlo, qhi, scale, bias, slack, ptr,
 # machinery in rtree_knn.py)
 # ---------------------------------------------------------------------------
 
-def _rect_md(q, lx, ly, hx, hy):
-    return mindist_rect(q[0], q[1], q[2], q[3], lx, ly, hx, hy)
-
-
-def _rect_mmd(q, lx, ly, hx, hy):
-    return minmaxdist_rect(q[0], q[1], q[2], q[3], lx, ly, hx, hy)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("cap", "k", "tighten", "chunk",
                                     "interpret"))
 def knn_join_level_fused(ids, qrects, lx, ly, hx, hy, child, tau, *,
                          cap: int, k: int, tighten: bool, chunk: int = 8,
-                         interpret: bool = True):
+                         interpret: bool):
     """Fused internal-level step for kNN-join outer rects: (B, C) frontier →
     compacted (B, cap) next frontier + tightened τ + valid/keep tallies, one
     pallas_call (see rtree_knn.py module docstring)."""
@@ -235,7 +110,7 @@ def knn_join_level_fused(ids, qrects, lx, ly, hx, hy, child, tau, *,
 
 @functools.partial(jax.jit, static_argnames=("k", "chunk", "interpret"))
 def knn_join_leaf_fused(ids, qrects, lx, ly, hx, hy, child, *, k: int,
-                        chunk: int = 8, interpret: bool = True):
+                        chunk: int = 8, interpret: bool):
     """Fused leaf-level step for kNN-join: the k best (id, squared rect
     MINDIST) per outer rect, one pallas_call — structurally leaf-specialized
     (no MINMAXDIST path exists in the leaf machinery at all)."""

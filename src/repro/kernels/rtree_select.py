@@ -1,11 +1,11 @@
 """Pallas TPU kernels: R-tree select BFS level step (paper §3, V-O1+O2).
 
 **Per-cell (unfused)** — ``select_level_masks``: one grid step evaluates the
-select predicate of one (query, frontier-node) cell.  The frontier node ids
-ride the **scalar-prefetch operand** (`PrefetchScalarGridSpec`): the
-BlockSpec index maps translate the id in SMEM into the HBM row of the node's
-SoA arrays, so Pallas' pipelined DMA fetches the node block for grid step
-k+1 *while step k computes* — the TPU-native equivalent of the paper's
+select predicate of ``chunk`` (query, frontier-node) cells.  The frontier
+node ids ride the **scalar-prefetch operand** (`PrefetchScalarGridSpec`):
+the BlockSpec index maps translate the id in SMEM into the HBM row of the
+node's SoA arrays, so Pallas' pipelined DMA fetches the node blocks of grid
+step k+1 *while step k computes* — the TPU-native equivalent of the paper's
 `pf_distance` software prefetching (O2).  The queue itself (O1) is the
 frontier array; compaction (compress-store analogue) runs as XLA
 cumsum+scatter outside the kernel (compaction.py) over a materialized
@@ -13,21 +13,18 @@ cumsum+scatter outside the kernel (compaction.py) over a materialized
 
 **Whole-level (fused)** — ``select_level_fused``: one ``pallas_call``
 processes the entire BFS level.  The grid tiles over (query,
-frontier-chunk) with multi-row node blocks, and the compress-store enqueue
-runs *inside* the kernel: mask → in-chunk prefix sum → scatter at a running
-per-query offset (SMEM) directly into the (1, cap) output frontier block,
-which stays resident in VMEM across the query's chunks — the TPU analogue
-of the paper's one-instruction ``_mm512_mask_compress_store`` enqueue (O1),
-with no (B, C, F) HBM intermediate and no post-kernel XLA round-trip.
+frontier-chunk), and the compress-store enqueue runs *inside* the kernel:
+mask → per-row lane-gather compaction → append at a running per-query
+offset (SMEM) into the (rows, 128) output frontier block, which stays
+resident in VMEM across the query's chunks — the TPU analogue of the
+paper's one-instruction ``_mm512_mask_compress_store`` enqueue (O1), with
+no (B, C, F) HBM intermediate and no post-kernel XLA round-trip.
 Bit-compatible with ``compact_rows`` over the flat level (same positions,
 same overflow parking); see ``ref.select_level_fused_ref`` for the jnp
-twin.  In-kernel scatter validates under interpret mode; Mosaic lowering on
-real TPU is tracked in ROADMAP.
+twin.  The in-kernel compaction handles node rows of at most 128 children.
 
 Layout: the kernels consume the level-global D1 (SoA) arrays — one (1, F)
-row per key excerpt per node.  F should be a multiple of 128 for full lane
-utilization on real TPUs; other F work but pad lanes (recorded as
-masked_waste in the roofline notes).
+row per key excerpt per node (fused_common describes the block plumbing).
 """
 from __future__ import annotations
 
@@ -38,31 +35,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .fused_common import chunk_tile as _chunk_tile
-from .fused_common import compress_store as _compress_store
-from .fused_common import d3_chunk_tile as _d3_chunk_tile
-from .fused_common import pad_frontier as _pad_frontier
+from . import fused_common as fc
 
 
-def _select_kernel(ids_ref, q_ref, lx_ref, ly_ref, hx_ref, hy_ref, child_ref,
-                   mask_ref):
-    b = pl.program_id(0)
-    c = pl.program_id(1)
-    nid = ids_ref[b, c]
-    qlx = q_ref[0, 0]
-    qly = q_ref[0, 1]
-    qhx = q_ref[0, 2]
-    qhy = q_ref[0, 3]
+def _select_row(q, refs, ok):
+    lx, ly, hx, hy, child = (ref[...] for ref in refs)
     # D1 predicate: 4 vector compares over the F child lanes.
-    m = (qlx <= hx_ref[0, :]) & (qhx >= lx_ref[0, :]) & \
-        (qly <= hy_ref[0, :]) & (qhy >= ly_ref[0, :])
-    m = m & (child_ref[0, :] >= 0) & (nid >= 0)
-    mask_ref[0, 0, :] = m.astype(jnp.int32)
+    m = (q[0] <= hx) & (q[2] >= lx) & (q[1] <= hy) & (q[3] >= ly)
+    return ((m & (child >= 0) & ok).astype(jnp.int32),)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def select_level_masks(ids, queries, lx, ly, hx, hy, child, *,
-                       interpret: bool = True):
+                       chunk: int = 8, interpret: bool):
     """Evaluate one BFS level for a batch of queries.
 
     ids:     (B, C) int32 frontier node ids (-1 pad) — scalar-prefetched.
@@ -71,45 +56,78 @@ def select_level_masks(ids, queries, lx, ly, hx, hy, child, *,
     child:   (N, F) int32 child ids.
     → mask (B, C, F) int32 qualify bitmask.
     """
-    b, c = ids.shape
-    n, f = lx.shape
-    safe_ids = jnp.maximum(ids, 0)
-
-    def node_map(bi, ci, ids_s):
-        return (ids_s[bi, ci], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, c),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda bi, ci, ids_s: (bi, 0)),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, f), lambda bi, ci, ids_s: (bi, ci, 0)),
-    )
-    fn = pl.pallas_call(
-        _select_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, f), jnp.int32),
-        interpret=interpret,
-    )
-    # Pass original ids (sign used in-kernel for validity); safe ids drive the
-    # index map so padding never DMAs out of bounds.
-    return fn(safe_ids, queries, lx, ly, hx, hy, child) * \
-        ((ids >= 0)[:, :, None]).astype(jnp.int32)
+    return fc.score_rows(_select_row, ids, queries,
+                         [lx, ly, hx, hy, child], n_out=1,
+                         out_dtype=jnp.int32, chunk=chunk,
+                         interpret=interpret)[0]
 
 
 # ---------------------------------------------------------------------------
 # Fused whole-level kernel: predicate + in-kernel compress-store enqueue
 # ---------------------------------------------------------------------------
 
+def _fused_select_call(tile_fn, ids, queries, arrays, *, cap: int,
+                       chunk: int, interpret: bool):
+    """The fused select level over any node layout: ``tile_fn(row_refs, r)``
+    → (lx, ly, hx, hy, ptr) each (R, F) for the chunk's slots."""
+    b, _ = ids.shape
+    ids, r, nc = fc.pad_frontier(ids, chunk)
+    cp = r * nc
+    na = len(arrays)
+    rows = fc.out_rows(cap)
+
+    def call(ids_g, q_g):
+        g = ids_g.shape[0]
+
+        def kernel(ids_ref, q_ref, *rest):
+            row_refs = rest[:na * r]
+            out_ref, cnt_ref, cnt_sm, aux_ref = rest[na * r:]
+            bi = pl.program_id(0)
+            ci = pl.program_id(1)
+
+            @pl.when(ci == 0)
+            def _():
+                cnt_sm[0] = 0
+                out_ref[...] = jnp.full((rows, fc.LANES), -1, jnp.int32)
+
+            glx, gly, ghx, ghy, ptr_t = tile_fn(row_refs, r)
+            valid = (ptr_t >= 0) & fc.slot_ok(ids_ref, bi * cp + ci * r, r)
+            qlx, qly, qhx, qhy = fc.query_scalars(q_ref, bi, 4)
+            m = (qlx <= ghx) & (qhx >= glx) & (qly <= ghy) & (qhy >= gly)
+            comp, counts = fc.compact_tile(m & valid, [ptr_t])
+            fc.append_rows(comp, counts, [out_ref], cnt_sm, aux_ref, cap)
+            cnt_ref[bi] = cnt_sm[0]
+
+        def slot(i, bi, ci, s, qs):
+            return s[bi * cp + ci * r + i]
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(g, nc),
+            in_specs=fc.row_specs(arrays, r, slot),
+            out_specs=[
+                pl.BlockSpec((None, rows, fc.LANES),
+                             lambda bi, ci, s, qs: (bi, 0, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((r, fc.LANES), jnp.int32)],
+        )
+        out, counts = pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((g, rows, fc.LANES), jnp.int32),
+                       jax.ShapeDtypeStruct((g,), jnp.int32)],
+            interpret=interpret,
+        )(ids_g.reshape(-1), q_g.reshape(-1), *fc.row_operands(arrays, r))
+        return out.reshape(g, rows * fc.LANES)[:, :cap], counts
+
+    out_ids, counts = fc.by_groups(call, [ids, queries], [-1, 0],
+                                    cp + 4)
+    return out_ids, counts, counts > cap
+
+
 @functools.partial(jax.jit, static_argnames=("cap", "chunk", "interpret"))
 def select_level_fused(ids, queries, lx, ly, hx, hy, child, *, cap: int,
-                       chunk: int = 8, interpret: bool = True):
+                       chunk: int = 8, interpret: bool):
     """Evaluate one BFS level AND compact the qualifying children, fused.
 
     ids:     (B, C) int32 frontier node ids (-1 pad) — scalar-prefetched.
@@ -120,61 +138,9 @@ def select_level_fused(ids, queries, lx, ly, hx, hy, child, *, cap: int,
     qualifying children (may exceed cap), overflow (B,) bool) — exactly
     ``compact_rows``'s contract applied to the level's flat (C·F) lanes.
     """
-    b, _ = ids.shape
-    n, f = lx.shape
-    ids, r, nc = _pad_frontier(ids, chunk)
-    safe = jnp.maximum(ids, 0)
-
-    def kernel(safe_ref, raw_ref, q_ref, *rest):
-        node_refs = rest[:5 * r]
-        out_ref, cnt_ref, cnt_sm = rest[5 * r:]
-        bi = pl.program_id(0)
-        ci = pl.program_id(1)
-
-        @pl.when(ci == 0)
-        def _():
-            cnt_sm[0] = 0
-            out_ref[0, :] = jnp.full((cap,), -1, jnp.int32)
-
-        glx, gly, ghx, ghy, child_t, valid = _chunk_tile(
-            raw_ref, node_refs, bi, ci, r)
-        qlx = q_ref[0, 0]
-        qly = q_ref[0, 1]
-        qhx = q_ref[0, 2]
-        qhy = q_ref[0, 3]
-        m = (qlx <= ghx) & (qhx >= glx) & (qly <= ghy) & (qhy >= gly)
-        m = (m & valid).reshape(-1)
-        _compress_store(m, [(child_t.reshape(-1), out_ref)], cnt_sm,
-                        cnt_ref, cap)
-
-    def bmap(bi, ci, s, rw):
-        return (bi, 0)
-
-    in_specs = [pl.BlockSpec((1, 4), bmap)]
-    for i in range(r):
-        def node_map(bi, ci, s, rw, i=i):
-            return (s[bi, ci * r + i], 0)
-        in_specs += [pl.BlockSpec((1, f), node_map)] * 5
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nc),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, cap), bmap),
-                   pl.BlockSpec((1, 1), bmap)],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-    )
-    fn = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, cap), jnp.int32),
-                   jax.ShapeDtypeStruct((b, 1), jnp.int32)],
-        interpret=interpret,
-    )
-    out_ids, counts = fn(safe, ids, *([queries] +
-                                      [lx, ly, hx, hy, child] * r))
-    counts = counts[:, 0]
-    return out_ids, counts, counts > cap
+    return _fused_select_call(fc.chunk_tile, ids, queries,
+                              [lx, ly, hx, hy, child], cap=cap, chunk=chunk,
+                              interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -187,31 +153,15 @@ def select_level_fused(ids, queries, lx, ly, hx, hy, child, *, cap: int,
 # geometry through the D1 kernel.
 # ---------------------------------------------------------------------------
 
-def _select_d3_kernel(ids_ref, q_ref, qlo_ref, qhi_ref, sc_ref, bi_ref,
-                      ptr_ref, mask_ref):
-    b = pl.program_id(0)
-    c = pl.program_id(1)
-    nid = ids_ref[b, c]
-    qlo = qlo_ref[0, :].astype(jnp.int32)
-    qhi = qhi_ref[0, :].astype(jnp.int32)
-    sx, sy = sc_ref[0, 0], sc_ref[0, 1]
-    bx, by = bi_ref[0, 0], bi_ref[0, 1]
-    # in-register dequantization: bias + code * pow2-scale is exact (codes
-    # have <= 8 significand bits), so these boxes match the jnp layout path
-    # bit-for-bit — the kernel can never disagree with its ref twin
-    lx = bx + (qlo >> 8).astype(jnp.float32) * sx
-    ly = by + (qlo & 0xFF).astype(jnp.float32) * sy
-    hx = bx + (qhi >> 8).astype(jnp.float32) * sx
-    hy = by + (qhi & 0xFF).astype(jnp.float32) * sy
-    m = (q_ref[0, 0] <= hx) & (q_ref[0, 2] >= lx) & \
-        (q_ref[0, 1] <= hy) & (q_ref[0, 3] >= ly)
-    m = m & (ptr_ref[0, :] >= 0) & (nid >= 0)
-    mask_ref[0, 0, :] = m.astype(jnp.int32)
+def _select_d3_row(q, refs, ok):
+    lx, ly, hx, hy = fc.d3_row_boxes(*refs[:4])
+    m = (q[0] <= hx) & (q[2] >= lx) & (q[1] <= hy) & (q[3] >= ly)
+    return ((m & (refs[4][...] >= 0) & ok).astype(jnp.int32),)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def select_level_masks_d3(ids, queries, qlo, qhi, scale, bias, ptr, *,
-                          interpret: bool = True):
+                          chunk: int = 8, interpret: bool):
     """Evaluate one quantized BFS level for a batch of queries.
 
     ids:     (B, C) int32 frontier node ids (-1 pad) — scalar-prefetched.
@@ -222,100 +172,20 @@ def select_level_masks_d3(ids, queries, qlo, qhi, scale, bias, ptr, *,
     → mask (B, C, F) int32 conservative qualify bitmask (superset of the
     exact D1 mask on the true child boxes).
     """
-    b, c = ids.shape
-    n, f = qlo.shape
-    safe_ids = jnp.maximum(ids, 0)
-
-    def node_map(bi, ci, ids_s):
-        return (ids_s[bi, ci], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, c),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda bi, ci, ids_s: (bi, 0)),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, f), node_map),
-            pl.BlockSpec((1, 2), node_map),
-            pl.BlockSpec((1, 2), node_map),
-            pl.BlockSpec((1, f), node_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, f), lambda bi, ci, ids_s: (bi, ci, 0)),
-    )
-    fn = pl.pallas_call(
-        _select_d3_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, f), jnp.int32),
-        interpret=interpret,
-    )
-    return fn(safe_ids, queries, qlo, qhi, scale, bias, ptr) * \
-        ((ids >= 0)[:, :, None]).astype(jnp.int32)
+    return fc.score_rows(_select_d3_row, ids, queries,
+                         [qlo, qhi, scale, bias, ptr], n_out=1,
+                         out_dtype=jnp.int32, chunk=chunk,
+                         interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "chunk", "interpret"))
 def select_level_fused_d3(ids, queries, qlo, qhi, scale, bias, ptr, *,
-                          cap: int, chunk: int = 8, interpret: bool = True):
+                          cap: int, chunk: int = 8, interpret: bool):
     """Fused quantized level: stream the packed uint16 code blocks, dequantize
     in-register, and compress-store the qualifying children — one
     pallas_call, same contract as ``select_level_fused`` (compact_rows over
     the flat level's conservative mask).
     """
-    b, _ = ids.shape
-    n, f = qlo.shape
-    ids, r, nc = _pad_frontier(ids, chunk)
-    safe = jnp.maximum(ids, 0)
-
-    def kernel(safe_ref, raw_ref, q_ref, *rest):
-        node_refs = rest[:5 * r]
-        out_ref, cnt_ref, cnt_sm = rest[5 * r:]
-        bi = pl.program_id(0)
-        ci = pl.program_id(1)
-
-        @pl.when(ci == 0)
-        def _():
-            cnt_sm[0] = 0
-            out_ref[0, :] = jnp.full((cap,), -1, jnp.int32)
-
-        glx, gly, ghx, ghy, ptr_t, valid = _d3_chunk_tile(
-            raw_ref, node_refs, bi, ci, r)
-        qlx = q_ref[0, 0]
-        qly = q_ref[0, 1]
-        qhx = q_ref[0, 2]
-        qhy = q_ref[0, 3]
-        m = (qlx <= ghx) & (qhx >= glx) & (qly <= ghy) & (qhy >= gly)
-        m = (m & valid).reshape(-1)
-        _compress_store(m, [(ptr_t.reshape(-1), out_ref)], cnt_sm,
-                        cnt_ref, cap)
-
-    def bmap(bi, ci, s, rw):
-        return (bi, 0)
-
-    in_specs = [pl.BlockSpec((1, 4), bmap)]
-    for i in range(r):
-        def node_map(bi, ci, s, rw, i=i):
-            return (s[bi, ci * r + i], 0)
-        in_specs += [pl.BlockSpec((1, f), node_map),
-                     pl.BlockSpec((1, f), node_map),
-                     pl.BlockSpec((1, 2), node_map),
-                     pl.BlockSpec((1, 2), node_map),
-                     pl.BlockSpec((1, f), node_map)]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nc),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, cap), bmap),
-                   pl.BlockSpec((1, 1), bmap)],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-    )
-    fn = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, cap), jnp.int32),
-                   jax.ShapeDtypeStruct((b, 1), jnp.int32)],
-        interpret=interpret,
-    )
-    out_ids, counts = fn(safe, ids, *([queries] +
-                                      [qlo, qhi, scale, bias, ptr] * r))
-    counts = counts[:, 0]
-    return out_ids, counts, counts > cap
+    return _fused_select_call(fc.d3_chunk_tile, ids, queries,
+                              [qlo, qhi, scale, bias, ptr], cap=cap,
+                              chunk=chunk, interpret=interpret)

@@ -129,21 +129,31 @@ def _serve_select(args, spec):
     for e in engines:
         e.warm("select", args.batch_size)
 
+    def shard_call(payload, e):
+        rows = e.range_select(payload)
+        return rows, bool(int(e.last_counters.overflow))
+
     with ShardPool(
-            shards=[(lambda payload, s=e: s.range_select(payload))
+            shards=[(lambda payload, e=e: shard_call(payload, e))
                     for e in engines],
             deadline_s=args.deadline) as pool:
         t0 = time.time()
         total = 0
+        answers = []
+        overflowed = False
         for b in range(args.batches):
-            res = pool.query(b % len(engines), qs[b])
+            res, ovf = pool.query(b % len(engines), qs[b])
             total += sum(len(r) for r in res)
+            answers.append(res)
+            overflowed |= ovf
         dt = time.time() - t0
     qps = args.batches * args.batch_size / dt
     print(f"served {args.batches} batches × {args.batch_size} queries in "
           f"{dt:.2f}s → {qps:,.0f} q/s, {total} result rows, "
-          f"{pool.reissues} straggler re-issues, {pool.failures} failures")
-    return {"qps": qps, "results": total}
+          f"{pool.reissues} straggler re-issues, {pool.failures} failures"
+          + (", WARNING: result-cap overflow" if overflowed else ""))
+    return {"qps": qps, "results": total, "failures": pool.failures,
+            "overflow": overflowed, "queries": qs, "answers": answers}
 
 
 def _serve_knn(args, spec):
@@ -163,10 +173,12 @@ def _serve_knn(args, spec):
     t0 = time.time()
     returned = 0
     overflowed = False
+    answers = []
     for b in range(args.batches):
         ids, dists, ovf = shards.knn(qs[b], args.k)
         returned += int((ids >= 0).sum())
         overflowed |= ovf
+        answers.append((ids, dists))
     dt = time.time() - t0
     qps = args.batches * args.batch_size / dt
     print(f"served {args.batches} batches × {args.batch_size} kNN queries "
@@ -174,7 +186,8 @@ def _serve_knn(args, spec):
           f"rows"
           + (", WARNING: frontier overflow — results may be approximate"
              if overflowed else ""))
-    return {"qps": qps, "neighbors": returned, "overflow": overflowed}
+    return {"qps": qps, "neighbors": returned, "overflow": overflowed,
+            "queries": qs, "answers": answers}
 
 
 def _serve_knn_join(args, spec):
@@ -191,10 +204,12 @@ def _serve_knn_join(args, spec):
     t0 = time.time()
     returned = 0
     overflowed = False
+    answers = []
     for b in range(args.batches):
         ids, dists, ovf = shards.knn_join(qs[b], args.k)
         returned += int((ids >= 0).sum())
         overflowed |= ovf
+        answers.append((ids, dists))
     dt = time.time() - t0
     qps = args.batches * args.batch_size / dt
     print(f"served {args.batches} batches × {args.batch_size} kNN-join "
@@ -202,7 +217,8 @@ def _serve_knn_join(args, spec):
           f"{qps:,.0f} q/s, {returned} neighbor rows"
           + (", WARNING: beam truncation — results may be approximate"
              if overflowed else ""))
-    return {"qps": qps, "neighbors": returned, "overflow": overflowed}
+    return {"qps": qps, "neighbors": returned, "overflow": overflowed,
+            "queries": qs, "answers": answers}
 
 
 def _serve_join(args, spec):
@@ -224,17 +240,20 @@ def _serve_join(args, spec):
     t0 = time.time()
     total = 0
     overflowed = False
+    answers = []
     for _ in range(args.batches):
         pairs, ovf = shards.join(probe_tree, result_cap=args.join_cap,
                                  o3=True, o4=True)
         total += len(pairs)
         overflowed |= ovf
+        answers.append(pairs)
     dt = time.time() - t0
     jps = args.batches / dt
     print(f"served {args.batches} joins × {n_probe} probes in {dt:.2f}s → "
           f"{jps:,.2f} joins/s, {total} pair rows"
           + (", WARNING: pair-frontier overflow" if overflowed else ""))
-    return {"joins_per_s": jps, "pairs": total, "overflow": overflowed}
+    return {"joins_per_s": jps, "pairs": total, "overflow": overflowed,
+            "queries": probes, "answers": answers}
 
 
 def _serve_knn_filtered(args, spec):
@@ -252,10 +271,12 @@ def _serve_knn_filtered(args, spec):
     t0 = time.time()
     returned = 0
     overflowed = False
+    answers = []
     for b in range(args.batches):
         ids, dists, ovf = shards.knn_filtered(qs[b], args.k)
         returned += int((ids >= 0).sum())
         overflowed |= ovf
+        answers.append((ids, dists))
     dt = time.time() - t0
     qps = args.batches * args.batch_size / dt
     print(f"served {args.batches} batches × {args.batch_size} filtered-kNN "
@@ -263,7 +284,8 @@ def _serve_knn_filtered(args, spec):
           f"{qps:,.0f} q/s, {returned} neighbor rows"
           + (", WARNING: frontier overflow — results may be approximate"
              if overflowed else ""))
-    return {"qps": qps, "neighbors": returned, "overflow": overflowed}
+    return {"qps": qps, "neighbors": returned, "overflow": overflowed,
+            "queries": qs, "answers": answers}
 
 
 def _serve_browse(args, spec):
@@ -283,12 +305,13 @@ def _serve_browse(args, spec):
         t0 = time.time()
         returned = 0
         overflowed = False
+        answers = []
         for b in range(args.batches):
             cursor = shards.browse(qs[b], args.k)
-            for _ in range(args.browse_steps):
-                ids, dists = cursor.next_batch()
-                returned += int((ids >= 0).sum())
+            steps = [cursor.next_batch() for _ in range(args.browse_steps)]
+            returned += sum(int((ids >= 0).sum()) for ids, _ in steps)
             overflowed |= bool(cursor.overflow.any())
+            answers.append(steps)
         dt = time.time() - t0
         qps = args.batches * args.batch_size / dt
         print(f"served {args.batches} distributed browse sessions × "
@@ -297,7 +320,8 @@ def _serve_browse(args, spec):
               f"{returned} neighbor rows"
               + (", WARNING: lost-bound crossed — results may be approximate"
                  if overflowed else ""))
-        return {"qps": qps, "neighbors": returned, "overflow": overflowed}
+        return {"qps": qps, "neighbors": returned, "overflow": overflowed,
+                "queries": qs, "answers": answers}
 
     rng = np.random.default_rng(args.seed)
     pts = rng.random((args.n, 2), dtype=np.float32)
@@ -315,12 +339,13 @@ def _serve_browse(args, spec):
     t0 = time.time()
     returned = 0
     overflowed = False
+    answers = []
     for b in range(args.batches):
         cursor = start(jnp.asarray(qs[b]))
-        for _ in range(args.browse_steps):
-            ids, dists = cursor.next_batch()
-            returned += int((ids >= 0).sum())
+        steps = [cursor.next_batch() for _ in range(args.browse_steps)]
+        returned += sum(int((ids >= 0).sum()) for ids, _ in steps)
         overflowed |= bool(cursor.overflow.any())
+        answers.append(steps)
     dt = time.time() - t0
     qps = args.batches * args.batch_size / dt
     print(f"served {args.batches} browse sessions × {args.batch_size} "
@@ -328,7 +353,8 @@ def _serve_browse(args, spec):
           f"{dt:.2f}s → {qps:,.0f} sessions·q/s, {returned} neighbor rows"
           + (", WARNING: lost-bound crossed — results may be approximate"
              if overflowed else ""))
-    return {"qps": qps, "neighbors": returned, "overflow": overflowed}
+    return {"qps": qps, "neighbors": returned, "overflow": overflowed,
+            "queries": qs, "answers": answers}
 
 
 def _queued_payloads(args, op, rng):
@@ -445,12 +471,18 @@ def _serve_queued(args, spec):
            "rows_per_dispatch": summary.get("rows_per_dispatch", 0.0),
            "reissues": summary["reissues"],
            "failures": summary["failures"],
-           "failed_requests": len(errors)}
+           "dispatch_failures": summary["dispatch_failures"],
+           "degraded_dispatches": summary["degraded_dispatches"],
+           "failed_requests": len(errors),
+           "queries": payloads,
+           "answers": [results.get(i) for i in range(len(payloads))]}
     # frontier occupancy across the fleet: each replica's last_counters
     # carries the per-step live/padded lane tallies its engines recorded,
     # so live/(live+padded) is the padded-work fraction the adaptive caps
     # policy is shaving (1.0 when no engine recorded occupancy)
-    ctrs = [e.last_counters for e in engines
+    # fetched to the host first: replicas live on disjoint device groups
+    import jax
+    ctrs = [jax.device_get(e.last_counters) for e in engines
             if getattr(e, "last_counters", None) is not None]
     if ctrs:
         total = ctrs[0]
@@ -476,7 +508,6 @@ def _serve_queued(args, spec):
             injected_delays=injector.injected["delays"],
             retries=summary["retries"],
             quarantines=summary["quarantines"],
-            degraded_dispatches=summary["degraded_dispatches"],
             deadline_exceeded=summary["deadline_exceeded"])
         # the robustness contract: chaos must never surface to clients
         assert out["failed_requests"] == 0, \
@@ -586,6 +617,8 @@ def main(argv=None):
         # add spurious re-issue work to the dryrun, never find a bug
         args.deadline = max(args.deadline, 60.0)
 
+    from . import compile_cache
+    compile_cache.enable()
     if args.mode == "lm":
         return _serve_lm(args)
     spec = traversal.get_spec(MODE_TO_SPEC[args.mode])

@@ -46,6 +46,7 @@ from repro.core import (join_vector, knn_join_vector, knn_vector, rtree,
 from repro.core.geometry import (brute_force_knn, brute_force_knn_join,
                                  mindist_matrix_np, mindist_rect_matrix_np)
 from repro.core.layouts import layout_names
+from repro.kernels.ops import KERNEL_CELLS
 
 from conftest import brute_join, brute_select, uniform_rects
 
@@ -54,19 +55,6 @@ from conftest import brute_join, brute_select, uniform_rects
 # every CLI/bench choices list — without touching call sites.
 LAYOUTS = layout_names()
 KERNEL_BACKENDS = ("xla", "pallas_interpret")
-
-# Which (layout, fused) cells each operator's kernel backends implement —
-# mirrors the engine guards: the level-global D1 SoA arrays have the full
-# kernel column, the quantized D3 streams have score kernels for
-# select/knn/knn_join plus the fused select variant, every other layout is
-# jnp-only (and knn_filtered has no kernel backend at all).
-KERNEL_CELLS = {
-    "select": {("d1", False), ("d1", True), ("d3", False), ("d3", True)},
-    "join": {("d1", False), ("d1", True)},
-    "knn": {("d1", False), ("d1", True), ("d3", False)},
-    "knn_join": {("d1", False), ("d1", True), ("d3", False)},
-    "knn_filtered": set(),
-}
 
 
 def _assert_bitwise_equal(a, b, ctx):

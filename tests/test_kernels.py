@@ -1,5 +1,5 @@
-"""Pallas kernels (interpret=True on CPU) ≡ pure-jnp oracles, swept over
-shapes and dtypes."""
+"""Pallas kernels (``pallas_interpret`` on CPU) ≡ pure-jnp oracles, swept
+over shapes and dtypes."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -178,3 +178,18 @@ def test_backend_resolution():
     assert ops.resolve_backend("auto") in ("pallas", "xla")
     with pytest.raises(ValueError):
         ops.resolve_backend("bogus")
+
+
+def test_compiled_pallas_refuses_off_tpu():
+    """backend='pallas' means the kernel compiled for the chip: off a TPU
+    it raises, naming the stage, instead of quietly interpreting."""
+    import jax
+    if jax.default_backend() == "tpu":
+        pytest.skip("on a TPU the compiled kernels run")
+    rng = np.random.default_rng(3)
+    lx, ly, hx, hy, child = _nodes(rng, 8, 128, np.float32)
+    ids = np.zeros((1, 1), np.int32)
+    qs = np.zeros((1, 4), np.float32)
+    with pytest.raises(RuntimeError, match="select/score"):
+        ops.select_level_masks(ids, qs, lx, ly, hx, hy, child,
+                               backend="pallas")
