@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from .compaction import _scatter_compact, beam_rows
 from .counters import OCC_STEPS, Counters, StageModel, occupancy_zeros
 from .geometry import DIST_PAD, DIST_VALID_MAX
+from ..runtime import trace
 
 
 def _occ_record(occ_live, occ_padded, *, step: int, valid, width: int,
@@ -420,14 +421,18 @@ def make_escalating_engine(build, tight_caps: Sequence[int],
     survived compaction in the same relative order, and padded slots never
     reach an emission stage (asserted across the oracle matrix per
     layout × operator cell).  The escalated run's ``Counters.escalations``
-    is bumped so the serve/bench layers can see the fallback rate.
+    is bumped so the serve/bench layers can see the fallback rate.  The
+    overflow read-back is the span ``repro.engine.overflow_check``.
 
     Hysteresis guard: a workload whose frontiers chronically exceed the
     tight caps would otherwise pay BOTH tiers on every batch.  After
     ``stick_after`` consecutive escalations the runner pins itself to the
     full tier (steady-state latency equals the static engine, recorded via
     ``stuck()``); the occupancy-adaptive sizing is a bet on the common
-    case, never a tax on the adversarial one.
+    case, never a tax on the adversarial one.  A pinned call runs the full
+    tier once, with no read-back, and is no escalation.  The counter
+    ``repro.engine.full_tier_calls`` counts every run of the full tier,
+    escalated or pinned.
 
     The returned runner exposes ``tight_caps`` / ``full_caps``,
     ``escalation_count()`` and ``stuck()`` for observability.  It is a
@@ -442,15 +447,15 @@ def make_escalating_engine(build, tight_caps: Sequence[int],
 
     def run(*args, **kw):
         if state["streak"] >= stick_after:
-            out = state["full"](*args, **kw)
-            ctr = dataclasses.replace(
-                out[-1], escalations=out[-1].escalations + 1)
-            state["escalations"] += 1
-            return out[:-1] + (ctr,)
+            trace.add("repro.engine.full_tier_calls")
+            return state["full"](*args, **kw)
         out = tight(*args, **kw)
-        if bool(jax.device_get(out[-1].overflow)):
+        with trace.span("repro.engine.overflow_check"):
+            overflowed = bool(jax.device_get(out[-1].overflow))
+        if overflowed:
             if state["full"] is None:
                 state["full"] = build(full_caps)
+            trace.add("repro.engine.full_tier_calls")
             out = state["full"](*args, **kw)
             state["escalations"] += 1
             state["streak"] += 1

@@ -29,6 +29,18 @@ Two execution paths share one public API (``range_select`` / ``knn`` /
 Host results and mesh results agree because both reduce to the same total
 order: candidates merge by (distance, global id), select/join rows by
 sorted global id — orders with no dependence on partition placement.
+
+Spans (runtime/trace.py): each public operator runs inside
+``repro.fleet.<operator>``.  On the host path it splits into
+``repro.fleet.route`` (router matrix, primary pick), ``repro.fleet.phase1``
+and ``repro.fleet.phase2`` (the partition loops) and ``repro.fleet.merge``
+(merging partition answers); every engine call is one
+``repro.fleet.enqueue`` (bucket, host-to-device copy, the call until it
+returns) then one ``repro.fleet.readback`` (the blocking copies of its
+answer and overflow flag back to the host), and one
+``repro.fleet.partition_calls``; summing the calls' Counters is
+``repro.fleet.counters``.  The mesh path has one enqueue and one
+readback per program call.
 """
 from __future__ import annotations
 
@@ -40,6 +52,7 @@ import numpy as np
 from repro.core import rtree, traversal
 from repro.core.geometry import intersects as np_intersects
 from repro.core.geometry import mindist_matrix_np, mindist_rect_matrix_np
+from repro.runtime import trace
 
 
 @dataclasses.dataclass
@@ -235,10 +248,12 @@ class SpatialShards:
                        ) -> Tuple[np.ndarray, np.ndarray, bool]:
         import jax.numpy as jnp
         prog = self._mesh_program(op, k=k)
-        ids, d, ctr = prog(jnp.asarray(queries))
+        with trace.span("repro.fleet.enqueue"):
+            ids, d, ctr = prog(jnp.asarray(queries))
         self.last_counters = ctr
-        return (np.asarray(ids).astype(np.int64),
-                np.asarray(d, np.float64), bool(int(ctr.overflow)))
+        with trace.span("repro.fleet.readback"):
+            return (np.asarray(ids).astype(np.int64),
+                    np.asarray(d, np.float64), bool(int(ctr.overflow)))
 
     # ------------------------------------------------------------------
     # routing + per-partition engines (host fallback)
@@ -265,6 +280,16 @@ class SpatialShards:
         return self._engines[key]
 
     @staticmethod
+    def _tally(acc, ctr):
+        """The running sum of a batch's per-partition Counters (``acc`` is
+        None before the first), timed as ``repro.fleet.counters``: each
+        field is a device array, so each add is one more dispatch."""
+        if acc is None:
+            return ctr
+        with trace.span("repro.fleet.counters"):
+            return acc + ctr
+
+    @staticmethod
     def _bucket(queries: np.ndarray) -> np.ndarray:
         """Pad a query subset to its next power-of-two row count so a
         (partition, params) pair compiles at most log2(max batch)+1 traces.
@@ -279,45 +304,56 @@ class SpatialShards:
             queries = np.concatenate([queries, pad], axis=0)
         return queries
 
+    @trace.spanned("repro.fleet.range_select")
     def range_select(self, queries: np.ndarray, result_cap: int = 4096
                      ) -> List[np.ndarray]:
         """Batched distributed select → per-query global rect id arrays."""
         import jax.numpy as jnp
         if self.mesh_enabled:
             prog = self._mesh_program("select", result_cap=result_cap)
-            ids, counts, ctr = prog(jnp.asarray(queries, np.float32))
+            with trace.span("repro.fleet.enqueue"):
+                ids, counts, ctr = prog(jnp.asarray(queries, np.float32))
             self.last_counters = ctr
-            ids = np.asarray(ids)
-            counts = np.asarray(counts)
-            return [np.sort(np.concatenate(
-                [ids[p, qi, :counts[p, qi]]
-                 for p in range(ids.shape[0])]).astype(np.int64))
-                for qi in range(len(queries))]
-        routing = self.route(queries)
+            with trace.span("repro.fleet.readback"):
+                ids = np.asarray(ids)
+                counts = np.asarray(counts)
+            with trace.span("repro.fleet.merge"):
+                return [np.sort(np.concatenate(
+                    [ids[p, qi, :counts[p, qi]]
+                     for p in range(ids.shape[0])]).astype(np.int64))
+                    for qi in range(len(queries))]
+        with trace.span("repro.fleet.route"):
+            routing = self.route(queries)
         results = [[] for _ in range(len(queries))]
         acc = None
-        for pi, part in enumerate(self.partitions):
-            hit = np.nonzero(routing[:, pi])[0]
-            if len(hit) == 0:
-                continue
-            sel = self.engine_for("select", pi, result_cap=result_cap)
-            sub = self._bucket(queries[hit])
-            ids, counts, ctr = sel(jnp.asarray(sub))
-            acc = ctr if acc is None else acc + ctr
-            ids = np.asarray(ids)
-            counts = np.asarray(counts)
-            for qi, local_q in enumerate(hit):
-                found = ids[qi, :counts[qi]]
-                results[local_q].append(part.ids[found])
+        with trace.span("repro.fleet.phase1"):
+            for pi, part in enumerate(self.partitions):
+                hit = np.nonzero(routing[:, pi])[0]
+                if len(hit) == 0:
+                    continue
+                sel = self.engine_for("select", pi, result_cap=result_cap)
+                with trace.span("repro.fleet.enqueue"):
+                    ids, counts, ctr = sel(jnp.asarray(
+                        self._bucket(queries[hit])))
+                trace.add("repro.fleet.partition_calls")
+                acc = self._tally(acc, ctr)
+                with trace.span("repro.fleet.readback"):
+                    ids = np.asarray(ids)
+                    counts = np.asarray(counts)
+                for qi, local_q in enumerate(hit):
+                    found = ids[qi, :counts[qi]]
+                    results[local_q].append(part.ids[found])
         if acc is not None:
             self.last_counters = acc
-        return [np.sort(np.concatenate(r)) if r else
-                np.empty((0,), np.int64) for r in results]
+        with trace.span("repro.fleet.merge"):
+            return [np.sort(np.concatenate(r)) if r else
+                    np.empty((0,), np.int64) for r in results]
 
     # ------------------------------------------------------------------
     # spatial join (probe rects × partitioned data)
     # ------------------------------------------------------------------
 
+    @trace.spanned("repro.fleet.join")
     def join(self, probe, result_cap: int = 1 << 17, o3: bool = False,
              o4: bool = False) -> Tuple[np.ndarray, bool]:
         """Distributed spatial join of a probe relation against the
@@ -356,12 +392,14 @@ class SpatialShards:
             probe_tree = cached[1]
             prog = self._mesh_program("join", outer_tree=probe_tree,
                                       **jn_params)
-            pairs, counts, ctr = prog()
+            with trace.span("repro.fleet.enqueue"):
+                pairs, counts, ctr = prog()
             self.last_counters = ctr
-            pairs = np.asarray(pairs)
-            counts = np.asarray(counts)
+            with trace.span("repro.fleet.readback"):
+                pairs = np.asarray(pairs)
+                counts = np.asarray(counts)
+                ovf = bool(int(ctr.overflow))
             rows = [pairs[p, :counts[p]] for p in range(pairs.shape[0])]
-            ovf = bool(int(ctr.overflow))
         else:
             rows = []
             ovf = False
@@ -376,18 +414,22 @@ class SpatialShards:
                         "join", probe_tree, part.tree, **jn_params))
                     self._engines[key] = cached
                 jn = cached[1]
-                pr, n_pairs, ctr = jn()
-                acc = ctr if acc is None else acc + ctr
-                pr = np.asarray(pr[:int(n_pairs)])
+                with trace.span("repro.fleet.enqueue"):
+                    pr, n_pairs, ctr = jn()
+                trace.add("repro.fleet.partition_calls")
+                acc = self._tally(acc, ctr)
+                with trace.span("repro.fleet.readback"):
+                    pr = np.asarray(pr[:int(n_pairs)])
+                    ovf |= bool(int(ctr.overflow))
                 rows.append(np.stack(
                     [pr[:, 0], part.ids[pr[:, 1]]], axis=1))
-                ovf |= bool(int(ctr.overflow))
             if acc is not None:
                 self.last_counters = acc
-        cat = np.concatenate(rows).astype(np.int64) if rows else \
-            np.empty((0, 2), np.int64)
-        order = np.lexsort((cat[:, 1], cat[:, 0]))
-        return cat[order], ovf
+        with trace.span("repro.fleet.merge"):
+            cat = np.concatenate(rows).astype(np.int64) if rows else \
+                np.empty((0, 2), np.int64)
+            order = np.lexsort((cat[:, 1], cat[:, 0]))
+            return cat[order], ovf
 
     # ------------------------------------------------------------------
     # distance operators (kNN / kNN-join / filtered kNN)
@@ -407,12 +449,17 @@ class SpatialShards:
         part = self.partitions[pi]
         b = len(queries)
         fn = self.engine_for(op, pi, k=k)
-        ids, dists, ctr = fn(jnp.asarray(self._bucket(queries)))
-        ids = np.asarray(ids)[:b]
-        dists = np.asarray(dists, np.float64)[:b]
+        with trace.span("repro.fleet.enqueue"):
+            ids, dists, ctr = fn(jnp.asarray(self._bucket(queries)))
+        trace.add("repro.fleet.partition_calls")
+        with trace.span("repro.fleet.readback"):
+            ids = np.asarray(ids)[:b]
+            dists = np.asarray(dists, np.float64)[:b]
+            ovf = bool(ctr.overflow)
         gids = np.where(ids >= 0, part.ids[np.maximum(ids, 0)], -1)
-        return gids, dists, bool(ctr.overflow), ctr
+        return gids, dists, ovf, ctr
 
+    @trace.spanned("repro.fleet.knn")
     def knn(self, points: np.ndarray, k: int
             ) -> Tuple[np.ndarray, np.ndarray, bool]:
         """Distributed exact kNN → (global ids (B, k), sq-dists (B, k),
@@ -436,9 +483,10 @@ class SpatialShards:
         points = np.asarray(points, np.float32)
         if self.mesh_enabled:
             return self._mesh_distance("knn", points, k)
-        dmat = mindist_matrix_np(points, self.router_mbrs)   # (B, P)
-        return self._two_phase_knn(points, k, dmat, "knn")
+        return self._two_phase_knn(points, k, "knn", mindist_matrix_np,
+                                   points)
 
+    @trace.spanned("repro.fleet.knn_join")
     def knn_join(self, qrects: np.ndarray, k: int
                  ) -> Tuple[np.ndarray, np.ndarray, bool]:
         """Distributed kNN-join → (global ids (B, k), sq-dists (B, k),
@@ -449,9 +497,10 @@ class SpatialShards:
         qrects = np.asarray(qrects, np.float32)
         if self.mesh_enabled:
             return self._mesh_distance("knn_join", qrects, k)
-        dmat = mindist_rect_matrix_np(qrects, self.router_mbrs)   # (B, P)
-        return self._two_phase_knn(qrects, k, dmat, "knn_join")
+        return self._two_phase_knn(qrects, k, "knn_join",
+                                   mindist_rect_matrix_np, qrects)
 
+    @trace.spanned("repro.fleet.knn_filtered")
     def knn_filtered(self, queries: np.ndarray, k: int
                      ) -> Tuple[np.ndarray, np.ndarray, bool]:
         """Distributed filtered kNN (core/knn_filtered.py): rows are
@@ -463,58 +512,67 @@ class SpatialShards:
         queries = np.asarray(queries, np.float32)
         if self.mesh_enabled:
             return self._mesh_distance("knn_filtered", queries, k)
-        dmat = mindist_matrix_np(queries[:, :2], self.router_mbrs)
-        return self._two_phase_knn(queries, k, dmat, "knn_filtered")
+        return self._two_phase_knn(queries, k, "knn_filtered",
+                                   mindist_matrix_np, queries[:, :2])
 
-    def _two_phase_knn(self, queries: np.ndarray, k: int, dmat: np.ndarray,
-                       op: str) -> Tuple[np.ndarray, np.ndarray, bool]:
+    def _two_phase_knn(self, queries: np.ndarray, k: int, op: str,
+                       router_dist, route_rows: np.ndarray
+                       ) -> Tuple[np.ndarray, np.ndarray, bool]:
         """Host-fallback two-phase routing for the distance operators:
         primary-partition answer → τ bound → τ-bounded secondary fan-out →
         deterministic cross-shard top-k merge.
 
-        ``dmat``: (B, P) exact query-to-partition-MBR squared MINDISTs;
-        ``op`` resolves the per-partition engine through the registry.
+        ``router_dist(route_rows, router_mbrs)`` gives the (B, P) exact
+        query-to-partition-MBR squared MINDISTs; ``op`` resolves the
+        per-partition engine through the registry.
         """
         b = len(queries)
         p = len(self.partitions)
-        primary = np.argmin(dmat, axis=1)
+        with trace.span("repro.fleet.route"):
+            dmat = router_dist(route_rows, self.router_mbrs)     # (B, P)
+            primary = np.argmin(dmat, axis=1)
         cand_ids = np.full((b, k), -1, np.int64)
         cand_d = np.full((b, k), np.inf)
         overflow = False
         acc = None
         # ---- phase 1: primary partitions ----
-        for pi in range(p):
-            sel = np.nonzero(primary == pi)[0]
-            if len(sel) == 0:
-                continue
-            gids, dists, ovf, ctr = self._run_partition(
-                op, pi, queries[sel], k)
-            acc = ctr if acc is None else acc + ctr
-            cand_ids[sel], cand_d[sel] = gids, dists
-            overflow |= ovf
+        with trace.span("repro.fleet.phase1"):
+            for pi in range(p):
+                sel = np.nonzero(primary == pi)[0]
+                if len(sel) == 0:
+                    continue
+                gids, dists, ovf, ctr = self._run_partition(
+                    op, pi, queries[sel], k)
+                acc = self._tally(acc, ctr)
+                cand_ids[sel], cand_d[sel] = gids, dists
+                overflow |= ovf
         # τ: current k-th best (inf when the primary held < k rects)
         tau = cand_d[:, k - 1].copy()
         # ---- phase 2: secondary partitions within τ ----
         # τ slack: partition distances are f32 (jax) while the router matrix
         # is exact f64, so widen the bound a hair — only ever *adds* fan-out,
         # never skips a partition that could hold a true k-th neighbor
-        for pi in range(p):
-            tau_cmp = tau * (1.0 + 1e-5) + 1e-30
-            sel = np.nonzero((primary != pi) & (dmat[:, pi] <= tau_cmp))[0]
-            if len(sel) == 0:
-                continue
-            gids, dists, ovf, ctr = self._run_partition(
-                op, pi, queries[sel], k)
-            acc = ctr if acc is None else acc + ctr
-            overflow |= ovf
-            merged_d = np.concatenate([cand_d[sel], dists], axis=1)
-            merged_i = np.concatenate([cand_ids[sel], gids], axis=1)
-            # top-k merge ordered by (distance, global id) — deterministic
-            # under cross-shard distance ties
-            order = np.lexsort((merged_i, merged_d))[:, :k]
-            cand_d[sel] = np.take_along_axis(merged_d, order, axis=1)
-            cand_ids[sel] = np.take_along_axis(merged_i, order, axis=1)
-            tau[sel] = cand_d[sel, k - 1]
+        with trace.span("repro.fleet.phase2"):
+            for pi in range(p):
+                tau_cmp = tau * (1.0 + 1e-5) + 1e-30
+                sel = np.nonzero((primary != pi)
+                                 & (dmat[:, pi] <= tau_cmp))[0]
+                if len(sel) == 0:
+                    continue
+                gids, dists, ovf, ctr = self._run_partition(
+                    op, pi, queries[sel], k)
+                acc = self._tally(acc, ctr)
+                overflow |= ovf
+                with trace.span("repro.fleet.merge"):
+                    merged_d = np.concatenate([cand_d[sel], dists], axis=1)
+                    merged_i = np.concatenate([cand_ids[sel], gids], axis=1)
+                    # top-k merge ordered by (distance, global id) —
+                    # deterministic under cross-shard distance ties
+                    order = np.lexsort((merged_i, merged_d))[:, :k]
+                    cand_d[sel] = np.take_along_axis(merged_d, order, axis=1)
+                    cand_ids[sel] = np.take_along_axis(merged_i, order,
+                                                       axis=1)
+                    tau[sel] = cand_d[sel, k - 1]
         if acc is not None:
             self.last_counters = acc
         return cand_ids, cand_d, overflow
@@ -523,6 +581,7 @@ class SpatialShards:
     # distributed distance browsing
     # ------------------------------------------------------------------
 
+    @trace.spanned("repro.fleet.browse")
     def browse(self, points: np.ndarray, k: int):
         """Open a distributed browsing session: per-partition
         ``BrowseState`` cursors with a cross-shard pool merge on every

@@ -67,6 +67,14 @@ the hypothesis schedule property in tests/test_spatial_shard.py and the
 chaos parity sweep in tests/test_chaos.py).  The batch-level ``overflow``
 flag is conservative — a request reports overflow if any request in its
 coalesced batch overflowed.
+
+Spans (runtime/trace.py): the runner's ``repro.queue.gather`` (waiting for
+and coalescing requests), ``repro.queue.assemble`` (concatenate + pad) and
+``repro.queue.slot_wait`` (blocked on the oldest in-flight dispatch); the
+dispatch worker's ``repro.queue.resolve`` (slicing + future resolution);
+and per request the time ``repro.queue.wait``, from ``submit`` to the start
+of its batch's dispatch.  Every span of one coalesced batch, the fleet's
+included, carries the batch's id.
 """
 from __future__ import annotations
 
@@ -83,6 +91,7 @@ import numpy as np
 
 from repro.core import traversal
 from repro.distributed.spatial_shard import SpatialShards
+from repro.runtime import trace
 from repro.runtime.health import HealthTracker
 from repro.runtime.straggler import ShardPool
 
@@ -107,6 +116,7 @@ class _Request:
     future: cf.Future           # resolves to this request's sliced result
     deadline: Optional[float]   # absolute time.monotonic() bound, or None
     off: int = 0                # row offset inside its coalesced batch
+    submitted: float = dataclasses.field(default_factory=time.perf_counter)
 
 
 class ServeQueue:
@@ -394,7 +404,9 @@ class ServeQueue:
     def _serve_loop(self) -> None:
         try:
             while True:
-                reqs = self._gather()
+                bid = trace.new_id()
+                with trace.span("repro.queue.gather", batch=bid):
+                    reqs = self._gather()
                 if reqs is None:
                     break
                 # fail-fast: a request already past its deadline never
@@ -409,18 +421,20 @@ class ServeQueue:
                     continue
                 # host-side assembly: concatenate + pow2-bucket pad —
                 # overlaps the device compute of the in-flight dispatches
-                off = 0
-                for r in live:
-                    r.off = off
-                    off += len(r.rows)
-                batch = SpatialShards._bucket(
-                    np.concatenate([r.rows for r in live], axis=0))
+                with trace.span("repro.queue.assemble", batch=bid):
+                    off = 0
+                    for r in live:
+                        r.off = off
+                        off += len(r.rows)
+                    batch = SpatialShards._bucket(
+                        np.concatenate([r.rows for r in live], axis=0))
                 while len(self._inflight) >= self.depth * len(self.replicas):
-                    self._inflight.popleft().result()
+                    with trace.span("repro.queue.slot_wait", batch=bid):
+                        self._inflight.popleft().result()
                 start = self._rr % len(self.replicas)
                 self._rr += 1
-                self._inflight.append(
-                    self._exec.submit(self._run_batch, start, batch, live))
+                self._inflight.append(self._exec.submit(
+                    self._run_batch, start, batch, live, bid))
             for fut in self._inflight:
                 fut.result()
             self._inflight.clear()
@@ -485,18 +499,31 @@ class ServeQueue:
         return self._fallback_call(batch)
 
     def _run_batch(self, start: int, batch: np.ndarray,
-                   reqs: List[_Request]) -> None:
+                   reqs: List[_Request], bid: int) -> None:
         """One coalesced dispatch, then per-request slicing and future
         resolution.  Any exception — engine, retry-budget, slicing — lands
-        in the request futures, never in the worker thread."""
-        try:
-            out = self._dispatch(start, batch, reqs)
-        except Exception as exc:
+        in the request futures, never in the worker thread.  Every span of
+        the dispatch, in this thread and in the pool's, carries the batch
+        id ``bid``."""
+        with trace.tag(batch=bid):
+            now = time.perf_counter()
             for r in reqs:
-                self._resolve_exc(r, exc)
-            return
-        if out is None:              # every request expired mid-retry
-            return
+                trace.add_time("repro.queue.wait", now - r.submitted)
+            try:
+                out = self._dispatch(start, batch, reqs)
+            except Exception as exc:
+                for r in reqs:
+                    self._resolve_exc(r, exc)
+                return
+            if out is None:              # every request expired mid-retry
+                return
+            with trace.span("repro.queue.resolve"):
+                self._deliver(batch, reqs, out)
+
+    def _deliver(self, batch: np.ndarray, reqs: List[_Request],
+                 out) -> None:
+        """Count the dispatched batch, then slice ``out`` per request and
+        resolve each future."""
         with self._slock:
             self.stats["batches"] += 1
             self.stats["requests"] += len(reqs)

@@ -38,6 +38,7 @@ when the serving loop raises.
 from __future__ import annotations
 
 import concurrent.futures as cf
+import contextvars
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -59,7 +60,8 @@ class ShardPool:
         self._by_shard: Dict[str, Dict[str, int]] = {}
         self._pool = cf.ThreadPoolExecutor(
             max_workers=max_workers
-            or len(self.shards) + max(len(self.spares), 1))
+            or len(self.shards) + max(len(self.spares), 1),
+            thread_name_prefix="shard-pool")
 
     def __enter__(self) -> "ShardPool":
         return self
@@ -108,7 +110,9 @@ class ShardPool:
         failure stats here, plus health signals (true latency even when the
         answer lands after the race was already won elsewhere)."""
         t0 = time.perf_counter()
-        fut = self._pool.submit(fn, payload)
+        # the engine call runs in the caller's context (its batch id and
+        # open span, runtime/trace.py): an executor does not copy it
+        fut = self._pool.submit(contextvars.copy_context().run, fn, payload)
 
         def _record(f: cf.Future) -> None:
             if f.cancelled():

@@ -222,6 +222,38 @@ def test_escalating_engine_repairs_overflow():
     assert not hasattr(plain, "escalation_count")
 
 
+def test_pinned_escalating_engine_counts_full_tier_calls_not_escalations():
+    """After ``stick_after`` escalations in a row the engine pins itself to
+    the full tier: later calls run that tier once, with no overflow
+    read-back, and count as full-tier calls, not as escalations."""
+    from repro.core import select_vector
+    from repro.runtime import trace
+    rng = np.random.default_rng(12)
+    rects = uniform_rects(rng, 3000, eps=0.004)
+    tree = rtree.build_rtree(rects, fanout=16)
+    lo = rng.random((4, 2)).astype(np.float32) * 0.6
+    qs = np.concatenate([lo, lo + np.float32(0.3)], axis=1)
+    full = caps.select_frontier_caps(tree, 4096)
+    esc = traversal.make_escalating_engine(
+        lambda c: select_vector.make_select_bfs(tree, caps=c,
+                                                result_cap=4096),
+        (1,) * len(full), full, stick_after=2)
+    before = trace.snapshot()
+    ctrs = [esc(qs)[-1] for _ in range(5)]
+    after = trace.snapshot()
+    assert esc.stuck()
+    assert esc.escalation_count() == 2
+    assert [int(c.escalations) for c in ctrs] == [1, 1, 0, 0, 0]
+
+    def delta(kind, name, field=None):
+        a, b = after[kind].get(name), before[kind].get(name)
+        if field is not None:
+            a, b = a and a[field], b and b[field]
+        return (a or 0) - (b or 0)
+    assert delta("counters", "repro.engine.full_tier_calls") == 5
+    assert delta("spans", "repro.engine.overflow_check", "count") == 2
+
+
 def test_counters_occupancy_recorded():
     """Engines record per-step live/padded lane tallies; occupancy() is
     the live fraction and the adaptive tier never reports lower occupancy
