@@ -35,10 +35,15 @@ Occupancy counters (the adaptive-caps observability surface):
   escalations        — overflow escalations taken by a two-tier engine
                        (traversal.make_escalating_engine): batches re-run on
                        the full-caps tier after the tight tier overflowed
+
+``total`` sums many Counters in one compiled program at a fixed arity
+(the host fan-out's per-partition sum, distributed/spatial_shard.py).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -158,3 +163,33 @@ class Counters:
 def zeros() -> Counters:
     z = jnp.zeros((), jnp.int64) if jax.config.jax_enable_x64 else jnp.zeros((), jnp.int32)
     return Counters(*([z] * len(dataclasses.fields(Counters))))
+
+
+@jax.jit
+def _sum(ctrs):
+    return functools.reduce(operator.add, ctrs)
+
+
+# one zero per leaf signature (aval: shape, dtype, weak type; and sharding)
+_ZERO_LEAVES: dict = {}
+
+
+def _zero_like(leaf):
+    if not isinstance(leaf, jax.Array):
+        return 0
+    key = (leaf.aval, leaf.sharding)
+    zero = _ZERO_LEAVES.get(key)
+    if zero is None:
+        zero = _ZERO_LEAVES[key] = jnp.zeros_like(leaf)
+    return zero
+
+
+def total(ctrs, arity: int) -> Counters:
+    """The sum of ``ctrs`` as one device program, not one eager add per
+    field per term.  The terms are padded to ``arity`` with zeros shaped
+    like the first term's leaves, so every count up to ``arity`` of terms
+    with one leaf signature runs the same compiled trace.  Exact integer
+    sums: equal, field by field, to folding ``+`` over ``ctrs``."""
+    ctrs = tuple(ctrs)
+    pad = Counters(*[_zero_like(v) for v in ctrs[0].tree_flatten()[0]])
+    return _sum(ctrs + (pad,) * (arity - len(ctrs)))

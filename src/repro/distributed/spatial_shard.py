@@ -38,9 +38,10 @@ and ``repro.fleet.phase2`` (the partition loops) and ``repro.fleet.merge``
 ``repro.fleet.enqueue`` (bucket, host-to-device copy, the call until it
 returns) then one ``repro.fleet.readback`` (the blocking copies of its
 answer and overflow flag back to the host), and one
-``repro.fleet.partition_calls``; summing the calls' Counters is
-``repro.fleet.counters``.  The mesh path has one enqueue and one
-readback per program call.
+``repro.fleet.partition_calls``.  The calls' Counters are collected as
+they return and summed once per operator call, as one compiled program
+(``_tally``): that sum is ``repro.fleet.counters``.  The mesh path has
+one enqueue and one readback per program call.
 """
 from __future__ import annotations
 
@@ -49,7 +50,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from repro.core import rtree, traversal
+from repro.core import counters, rtree, traversal
 from repro.core.geometry import intersects as np_intersects
 from repro.core.geometry import mindist_matrix_np, mindist_rect_matrix_np
 from repro.runtime import trace
@@ -87,8 +88,9 @@ class SpatialShards:
         self._browse_starts = {}
         # merged Counters of the last batch: mesh programs set it from the
         # collective merge; host fallbacks sum the per-partition Counters
-        # (so scalar flags like overflow become "how many partition-batches
-        # tripped it" — use truthiness, and .occupancy() for lane waste)
+        # (_tally; so scalar flags like overflow become "how many
+        # partition-batches tripped it" — use truthiness, and .occupancy()
+        # for lane waste)
         self.last_counters = None
 
     @classmethod
@@ -279,15 +281,23 @@ class SpatialShards:
                 op, self.partitions[pi].tree, **params)
         return self._engines[key]
 
-    @staticmethod
-    def _tally(acc, ctr):
-        """The running sum of a batch's per-partition Counters (``acc`` is
-        None before the first), timed as ``repro.fleet.counters``: each
-        field is a device array, so each add is one more dispatch."""
-        if acc is None:
-            return ctr
-        with trace.span("repro.fleet.counters"):
-            return acc + ctr
+    @property
+    def _max_calls(self) -> int:
+        """The most engine calls one host-path operator call makes: two
+        per partition, the distance operators' two phases."""
+        return 2 * len(self.partitions)
+
+    def _tally(self, ctrs) -> None:
+        """Set ``last_counters`` to the sum of one host-path operator
+        call's per-partition Counters (unchanged when it made no engine
+        call), timed as ``repro.fleet.counters``.  The sum is one compiled
+        program (``counters.total``) padded to ``_max_calls`` terms, so it
+        compiles once per operator and stays on the device: one launch per
+        operator call, where adding the calls one by one cost an eager
+        dispatch per field per call."""
+        if ctrs:
+            with trace.span("repro.fleet.counters"):
+                self.last_counters = counters.total(ctrs, self._max_calls)
 
     @staticmethod
     def _bucket(queries: np.ndarray) -> np.ndarray:
@@ -325,7 +335,7 @@ class SpatialShards:
         with trace.span("repro.fleet.route"):
             routing = self.route(queries)
         results = [[] for _ in range(len(queries))]
-        acc = None
+        ctrs = []
         with trace.span("repro.fleet.phase1"):
             for pi, part in enumerate(self.partitions):
                 hit = np.nonzero(routing[:, pi])[0]
@@ -336,15 +346,14 @@ class SpatialShards:
                     ids, counts, ctr = sel(jnp.asarray(
                         self._bucket(queries[hit])))
                 trace.add("repro.fleet.partition_calls")
-                acc = self._tally(acc, ctr)
+                ctrs.append(ctr)
                 with trace.span("repro.fleet.readback"):
                     ids = np.asarray(ids)
                     counts = np.asarray(counts)
                 for qi, local_q in enumerate(hit):
                     found = ids[qi, :counts[qi]]
                     results[local_q].append(part.ids[found])
-        if acc is not None:
-            self.last_counters = acc
+        self._tally(ctrs)
         with trace.span("repro.fleet.merge"):
             return [np.sort(np.concatenate(r)) if r else
                     np.empty((0,), np.int64) for r in results]
@@ -403,7 +412,7 @@ class SpatialShards:
         else:
             rows = []
             ovf = False
-            acc = None
+            ctrs = []
             for pi, part in enumerate(self.partitions):
                 # join engines close over BOTH trees, so the cache entry is
                 # valid only for the same probe-tree object
@@ -417,14 +426,13 @@ class SpatialShards:
                 with trace.span("repro.fleet.enqueue"):
                     pr, n_pairs, ctr = jn()
                 trace.add("repro.fleet.partition_calls")
-                acc = self._tally(acc, ctr)
+                ctrs.append(ctr)
                 with trace.span("repro.fleet.readback"):
                     pr = np.asarray(pr[:int(n_pairs)])
                     ovf |= bool(int(ctr.overflow))
                 rows.append(np.stack(
                     [pr[:, 0], part.ids[pr[:, 1]]], axis=1))
-            if acc is not None:
-                self.last_counters = acc
+            self._tally(ctrs)
         with trace.span("repro.fleet.merge"):
             cat = np.concatenate(rows).astype(np.int64) if rows else \
                 np.empty((0, 2), np.int64)
@@ -534,7 +542,7 @@ class SpatialShards:
         cand_ids = np.full((b, k), -1, np.int64)
         cand_d = np.full((b, k), np.inf)
         overflow = False
-        acc = None
+        ctrs = []
         # ---- phase 1: primary partitions ----
         with trace.span("repro.fleet.phase1"):
             for pi in range(p):
@@ -543,7 +551,7 @@ class SpatialShards:
                     continue
                 gids, dists, ovf, ctr = self._run_partition(
                     op, pi, queries[sel], k)
-                acc = self._tally(acc, ctr)
+                ctrs.append(ctr)
                 cand_ids[sel], cand_d[sel] = gids, dists
                 overflow |= ovf
         # τ: current k-th best (inf when the primary held < k rects)
@@ -561,7 +569,7 @@ class SpatialShards:
                     continue
                 gids, dists, ovf, ctr = self._run_partition(
                     op, pi, queries[sel], k)
-                acc = self._tally(acc, ctr)
+                ctrs.append(ctr)
                 overflow |= ovf
                 with trace.span("repro.fleet.merge"):
                     merged_d = np.concatenate([cand_d[sel], dists], axis=1)
@@ -573,8 +581,7 @@ class SpatialShards:
                     cand_ids[sel] = np.take_along_axis(merged_i, order,
                                                        axis=1)
                     tau[sel] = cand_d[sel, k - 1]
-        if acc is not None:
-            self.last_counters = acc
+        self._tally(ctrs)
         return cand_ids, cand_d, overflow
 
     # ------------------------------------------------------------------
@@ -616,8 +623,9 @@ class SpatialShards:
 
         Host path: every partition's engine at every power-of-two bucket up
         to ``batch`` (routed subsets can land in any bucket ≤ the full
-        batch's).  Mesh path: the single SPMD program at the serving batch
-        shape (subsets never change shape there).  ``join`` warms against
+        batch's), then ``_tally``'s sum of their Counters.  Mesh path: the
+        single SPMD program at the serving batch shape (subsets never change
+        shape there).  ``join`` warms against
         ``probe`` (rects or RTree) — its engines close over the probe tree.
         """
         import jax.numpy as jnp
@@ -649,7 +657,8 @@ class SpatialShards:
         for pi in range(len(self.partitions)):
             fn = self.engine_for(op, pi, **params)
             for bk in buckets:
-                fn(jnp.asarray(np.zeros((bk, width), np.float32)))
+                ctr = fn(jnp.asarray(np.zeros((bk, width), np.float32)))[-1]
+        counters.total([ctr], self._max_calls)
 
     # preserved spellings of the historical per-operator warmups
     def warm_knn(self, batch: int, k: int) -> None:

@@ -9,9 +9,12 @@ the sharded suite's dominant tier-1 cost.
 """
 import time
 
+import jax
 import numpy as np
 import pytest
 
+from repro.core import traversal
+from repro.core.geometry import mindist_matrix_np, mindist_rect_matrix_np
 from repro.distributed.spatial_shard import SpatialShards
 from repro.launch.queue import QueueClosed, ServeQueue
 from repro.runtime.straggler import ShardPool
@@ -479,3 +482,76 @@ def test_queue_over_replicas_bitexact(shard_cache):
         ref_ids, ref_d, _ = shards.knn(rows, 4)
         np.testing.assert_array_equal(ids, ref_ids)
         np.testing.assert_array_equal(d, ref_d)
+
+
+# ---------------------------------------------------------------------------
+# The host path's per-partition Counters, summed once per operator call
+# ---------------------------------------------------------------------------
+
+def _field_sums(ctrs):
+    out = {}
+    for c in ctrs:
+        for name, v in c.asdict().items():
+            out[name] = np.add(out.get(name, 0), v)
+    return {name: v.tolist() if np.ndim(v) else int(v)
+            for name, v in out.items()}
+
+
+def _distance_rows(op, pts):
+    """Query rows of a distance operator around ``pts``, and the columns
+    its router measures."""
+    if op == "knn":
+        return pts, pts
+    if op == "knn_join":
+        rows = np.concatenate([pts - 0.002, pts + 0.002], axis=1)
+        return rows, rows
+    rows = np.concatenate([pts, pts - 0.2, pts + 0.2], axis=1)
+    return rows, pts
+
+
+@pytest.mark.parametrize("op", ["range_select", "join", "knn", "knn_join",
+                                "knn_filtered"])
+def test_host_counters_equal_the_sum_of_the_engine_calls(shard_cache,
+                                                         monkeypatch, op):
+    """``last_counters`` after a host-path operator call is, field by field
+    and bit for bit, the sum of the Counters its engine calls returned,
+    and stays on the device; each distance operator makes phase-2 calls
+    here, so the sum spans both phases."""
+    rects, shards = _queue_fleet(shard_cache)
+    rng = np.random.default_rng(53)
+    m = shards.router_mbrs
+    # points on the partitions' right edges: their neighbours straddle it
+    edges = np.stack([m[:, 2], (m[:, 1] + m[:, 3]) / 2], axis=1)
+    pts = np.concatenate([rng.random((8, 2)), edges]).astype(np.float32)
+    seen = []
+
+    def record(fn):
+        def call(*a, **kw):
+            out = fn(*a, **kw)
+            seen.append(out[-1])
+            return out
+        return call
+
+    if op == "join":
+        build = traversal.build
+        monkeypatch.setattr(traversal, "build",
+                            lambda *a, **kw: record(build(*a, **kw)))
+        shards.join(np.concatenate([pts, pts + 0.01], axis=1))
+    else:
+        engine_for = shards.engine_for
+        monkeypatch.setattr(shards, "engine_for",
+                            lambda *a, **kw: record(engine_for(*a, **kw)))
+        if op == "range_select":
+            shards.range_select(np.concatenate([pts, pts + 0.3], axis=1))
+        else:
+            rows, route = _distance_rows(op, pts)
+            getattr(shards, op)(rows, 4)
+            dist = mindist_rect_matrix_np if op == "knn_join" \
+                else mindist_matrix_np
+            primaries = np.unique(np.argmin(
+                dist(route, shards.router_mbrs), axis=1))
+            assert len(seen) > len(primaries)
+    assert len(seen) >= 2
+    assert shards.last_counters.asdict() == _field_sums(seen)
+    assert all(isinstance(v, jax.Array)
+               for v in shards.last_counters.tree_flatten()[0])

@@ -9,6 +9,7 @@ import time
 import numpy as np
 import pytest
 
+from repro.core import counters
 from repro.core.geometry import mindist_matrix_np
 from repro.distributed.spatial_shard import SpatialShards
 from repro.launch.queue import ServeQueue
@@ -283,3 +284,56 @@ def test_direct_fleet_calls_count_partition_calls(fleet):
     assert got == len(_implied_calls(rects, shards, rows))
     assert after["spans"]["repro.fleet.knn"]["count"] \
         == before["spans"].get("repro.fleet.knn", {"count": 0})["count"] + 1
+
+
+def _deltas(before, after, name):
+    return (after["counters"].get("repro.fleet.partition_calls", 0)
+            - before["counters"].get("repro.fleet.partition_calls", 0),
+            after["spans"][name]["count"]
+            - before["spans"].get(name, {"count": 0})["count"])
+
+
+def _one_partition_row(shards):
+    """A query point at the middle of partition 0, whose nearest point
+    lies in that partition alone."""
+    m = shards.partitions[0].mbr
+    return np.array([[(m[0] + m[2]) / 2, (m[1] + m[3]) / 2]], np.float32)
+
+
+def test_counters_sum_once_per_host_fleet_call(fleet):
+    """``repro.fleet.counters`` counts one sum per host-path operator
+    call, whether the call touched one partition or all of them."""
+    rects, shards = fleet
+    p = len(shards.partitions)
+    rows = np.random.default_rng(45).random((16, 2)).astype(np.float32)
+    cases = [(lambda: shards.knn(_one_partition_row(shards), K), 1, 1),
+             (lambda: shards.knn(rows, K), p, 2 * p),
+             (lambda: shards.range_select(
+                 np.array([[0, 0, 1, 1]], np.float32)), p, p)]
+    for run, lo, hi in cases:
+        before = trace.snapshot()
+        run()
+        calls, sums = _deltas(before, trace.snapshot(),
+                              "repro.fleet.counters")
+        assert lo <= calls <= hi
+        assert sums == 1
+
+
+def test_counters_sum_compiles_in_warm_and_never_again(fleet):
+    """The sum is padded to a fixed number of terms, so ``warm`` compiles
+    it once for the operator and fleet calls making 1 to P or more engine
+    calls add no trace of it."""
+    rects, shards = fleet
+    counters._sum.clear_cache()
+    shards.warm("knn", 16, k=K)
+    assert counters._sum._cache_size() == 1
+    rng = np.random.default_rng(46)
+    made = set()
+    for rows in (_one_partition_row(shards),
+                 rng.random((16, 2)).astype(np.float32),
+                 rng.random((5, 2)).astype(np.float32)):
+        before = trace.snapshot()
+        shards.knn(rows, K)
+        made.add(_deltas(before, trace.snapshot(), "repro.fleet.knn")[0])
+    assert min(made) == 1 and max(made) >= len(shards.partitions)
+    assert counters._sum._cache_size() == 1
