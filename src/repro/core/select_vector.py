@@ -43,6 +43,12 @@ from .layouts import (LevelD0, LevelD1, LevelD2, LevelD3, d0_unpack,
 from .rtree import RTree
 
 
+# Leaf frontier slots the jnp path scores per step of its leaf loop: with a
+# fanout of 64, one block is 256 x 64 = 16,384 lanes a query row (128 lane
+# rows of the v5e's 128-wide vregs), and the loop stops at the last block
+# that holds a live leaf (traversal._blocked_leaf).
+LEAF_BLOCK = 256
+
 # ---------------------------------------------------------------------------
 # Layout-specific batched predicate evaluation
 # ---------------------------------------------------------------------------
@@ -223,7 +229,8 @@ def make_select_bfs(tree: RTree, layout: str = "d1", result_cap: int = 4096,
             SELECT_SPEC, height=tree.height, caps=caps_,
             result_cap=result_cap, score=score,
             fused_level=fused_level if fused else None,
-            count_only=count_only)
+            count_only=count_only,
+            leaf_block=LEAF_BLOCK if backend is None else None)
         if count_only:
             def fn(queries: jax.Array):
                 _, counts, ctr = run(ctx, queries)

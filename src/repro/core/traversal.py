@@ -47,7 +47,7 @@ from typing import Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from .compaction import _scatter_compact, beam_rows
+from .compaction import _scatter_compact, beam_rows, scatter_append
 from .counters import OCC_STEPS, Counters, StageModel, occupancy_zeros
 from .geometry import DIST_PAD, DIST_VALID_MAX
 from ..runtime import trace
@@ -57,10 +57,12 @@ def _occ_record(occ_live, occ_padded, *, step: int, valid, width: int,
                 batch: int):
     """Fold one level's frontier occupancy into the per-step vectors:
     ``valid`` is the (B, width) liveness mask of the frontier the level
-    scored; padded slots are the allocated-but-empty remainder."""
+    scored; padded slots are the allocated-but-empty remainder of the
+    ``width`` slots scored per row (a device scalar for the blocked leaf
+    step, which scores only the blocks that hold a live node)."""
     slot = min(step, OCC_STEPS - 1)
     live = valid.sum().astype(jnp.int32)
-    total = jnp.int32(batch * width)
+    total = jnp.asarray(batch * width, jnp.int32)
     return (occ_live.at[slot].add(live),
             occ_padded.at[slot].add(total - live))
 
@@ -168,10 +170,61 @@ def _apply_delta(acc: dict, delta: Optional[dict], *, fcnt, f, stages, hits):
             acc[key] = acc[key] + val
 
 
+def _live_extent_blocks(valid: jax.Array, block: int) -> jax.Array:
+    """Blocks of ``block`` frontier slots, from slot 0, that cover the last
+    live slot of any row of the (B, W) liveness mask ``valid``."""
+    slot = jnp.arange(1, valid.shape[1] + 1, dtype=jnp.int32)
+    extent = jnp.max(jnp.where(valid, slot, 0))
+    return (extent + block - 1) // block
+
+
+def _blocked_leaf(score, ctx, frontier, qargs, *, block: int,
+                  n_blocks: jax.Array, result_cap: int, emit: bool):
+    """The leaf step of a mask engine, ``block`` frontier slots at a time.
+
+    Scores blocks 0 .. ``n_blocks`` - 1 of the (B, W) leaf frontier (W
+    padded to whole blocks) in a ``lax.while_loop`` and appends each
+    block's hits to a (B, result_cap + 1) buffer at each row's running
+    count, overflow parking in the spare slot as ``_scatter_compact`` does.
+    Blocks are visited in frontier order, so the ids and their order are
+    those of one dense compaction of the whole level; memory is one block's
+    (B, block * F) lanes plus the result buffer, whatever W.  Returns
+    (values | None, counts (B,) — totals, may exceed result_cap, hits, f,
+    stages)."""
+    b, w = frontier[0].shape
+    pad = -w % block
+    frontier = tuple(jnp.pad(fr, ((0, 0), (0, pad)), constant_values=-1)
+                     for fr in frontier)
+    static = {}
+
+    def body(carry):
+        j, bufs, counts = carry
+        blk = tuple(jax.lax.dynamic_slice_in_dim(fr, j * block, block, 1)
+                    for fr in frontier)
+        mask, values, f, stages, delta = score(ctx, 0, blk, qargs)
+        if delta is not None:
+            raise ValueError("the blocked leaf step takes the dense "
+                             "counter model only")
+        static.update(f=f, stages=stages)
+        if emit:
+            bufs, c = scatter_append(bufs, values, mask, counts, -1)
+        else:
+            c = mask.sum(axis=1).astype(jnp.int32)
+        return j + 1, tuple(bufs), counts + c
+
+    bufs = tuple(jnp.full((b, result_cap + 1), -1, jnp.int32)
+                 for _ in frontier) if emit else ()
+    _, bufs, counts = jax.lax.while_loop(
+        lambda carry: carry[0] < n_blocks, body,
+        (jnp.int32(0), bufs, jnp.zeros((b,), jnp.int32)))
+    res = tuple(buf[:, :result_cap] for buf in bufs) if emit else None
+    return res, counts, counts.sum(), static["f"], static["stages"]
+
+
 def make_mask_engine(spec: OperatorSpec, *, height: int,
                      caps: Sequence[int], result_cap: int, score,
                      fused_level=None, count_only: bool = False,
-                     n_streams: int = 1):
+                     n_streams: int = 1, leaf_block: Optional[int] = None):
     """Build the jitted level loop for a mask operator.
 
     ``score(ctx, li, frontier, qargs)`` → (mask (B, M) bool, values — an
@@ -179,8 +232,11 @@ def make_mask_engine(spec: OperatorSpec, *, height: int,
     stages, delta).  ``fused_level(ctx, li, frontier, qargs, cap)`` → the
     whole-level alternative: (values — tuple of (B, cap), qcnt (B,),
     overflow (B,), f, stages, delta); the engine then only routes compacted
-    frontiers.  Returns ``run(ctx, *qargs)`` → (values | None, counts,
-    Counters).
+    frontiers.  ``leaf_block``: an unfused leaf frontier wider than this
+    many slots is scored in blocks of it (``_blocked_leaf``), only as far
+    as its last live slot; the occupancy counters then count the slots of
+    the blocks scored.  Returns ``run(ctx, *qargs)`` → (values | None,
+    counts, Counters).
     """
     caps = tuple(caps)
     sm = spec.stage_model
@@ -205,9 +261,15 @@ def make_mask_engine(spec: OperatorSpec, *, height: int,
             cap = result_cap if leaf else caps[height - 1 - li]
             fvalid = frontier[0] >= 0
             fcnt = fvalid.sum(axis=1)
+            width = frontier[0].shape[1]
+            blocked = (leaf and fused_level is None and leaf_block is not None
+                       and width > leaf_block)
+            if blocked:
+                n_blocks = _live_extent_blocks(fvalid, leaf_block)
+                width = n_blocks * leaf_block
             occ_live, occ_padded = _occ_record(
                 occ_live, occ_padded, step=height - 1 - li, valid=fvalid,
-                width=frontier[0].shape[1], batch=b)
+                width=width, batch=b)
             if fused_level is not None:
                 vals, qcnt, o, f, stages, delta = fused_level(
                     ctx, li, frontier, qargs, cap)
@@ -225,17 +287,26 @@ def make_mask_engine(spec: OperatorSpec, *, height: int,
                     ovf = ovf | o
                     enq = enq + hits
             else:
-                mask, values, f, stages, delta = score(ctx, li, frontier,
-                                                       qargs)
-                hits = mask.sum()
+                if blocked:
+                    outs, qcnt, hits, f, stages = _blocked_leaf(
+                        score, ctx, frontier, qargs, block=leaf_block,
+                        n_blocks=n_blocks, result_cap=result_cap,
+                        emit=not count_only)
+                    delta = None
+                else:
+                    mask, values, f, stages, delta = score(ctx, li, frontier,
+                                                           qargs)
+                    hits = mask.sum()
+                    qcnt = mask.sum(axis=1).astype(jnp.int32)
                 disp += sm.leaf if leaf else sm.inner
                 if leaf:
-                    counts = mask.sum(axis=1).astype(jnp.int32)
+                    counts = qcnt
                     if not count_only:
-                        outs, _, o = _scatter_compact(values, mask,
-                                                      result_cap, -1)
+                        if not blocked:
+                            outs = _scatter_compact(values, mask,
+                                                    result_cap, -1)[0]
                         res = tuple(outs)
-                        ovf = ovf | o
+                        ovf = ovf | (counts > result_cap)
                     if spec.leaf_enqueue:
                         enq = enq + hits
                 else:

@@ -38,7 +38,10 @@ and ``repro.fleet.phase2`` (the partition loops) and ``repro.fleet.merge``
 ``repro.fleet.enqueue`` (bucket, host-to-device copy, the call until it
 returns) then one ``repro.fleet.readback`` (the blocking copies of its
 answer and overflow flag back to the host), and one
-``repro.fleet.partition_calls``.  The calls' Counters are collected as
+``repro.fleet.partition_calls``.  ``range_select`` maps each call's local
+ids to global ids per row in ``repro.fleet.ids``, and counts the ids it
+returns in ``repro.fleet.result_ids`` and the rows a result cap cut short
+in ``repro.fleet.overflowed_rows``.  The calls' Counters are collected as
 they return and summed once per operator call, as one compiled program
 (``_tally``): that sum is ``repro.fleet.counters``.  The mesh path has
 one enqueue and one readback per program call.
@@ -54,6 +57,16 @@ from repro.core import counters, rtree, traversal
 from repro.core.geometry import intersects as np_intersects
 from repro.core.geometry import mindist_matrix_np, mindist_rect_matrix_np
 from repro.runtime import trace
+
+
+class SelectRows(list):
+    """``range_select``'s answer: one sorted array of global ids per query
+    row, and ``overflowed``, the rows that held more ids than a partition's
+    result cap, whose arrays are therefore incomplete."""
+
+    def __init__(self, rows, overflowed):
+        super().__init__(rows)
+        self.overflowed = overflowed
 
 
 @dataclasses.dataclass
@@ -316,8 +329,10 @@ class SpatialShards:
 
     @trace.spanned("repro.fleet.range_select")
     def range_select(self, queries: np.ndarray, result_cap: int = 4096
-                     ) -> List[np.ndarray]:
-        """Batched distributed select → per-query global rect id arrays."""
+                     ) -> SelectRows:
+        """Batched distributed select → per-query global rect id arrays
+        (a ``SelectRows``: rows cut short by ``result_cap`` are listed in
+        its ``overflowed``)."""
         import jax.numpy as jnp
         if self.mesh_enabled:
             prog = self._mesh_program("select", result_cap=result_cap)
@@ -327,14 +342,17 @@ class SpatialShards:
             with trace.span("repro.fleet.readback"):
                 ids = np.asarray(ids)
                 counts = np.asarray(counts)
+            over = (counts > result_cap).any(axis=0)
             with trace.span("repro.fleet.merge"):
-                return [np.sort(np.concatenate(
+                rows = [np.sort(np.concatenate(
                     [ids[p, qi, :counts[p, qi]]
                      for p in range(ids.shape[0])]).astype(np.int64))
                     for qi in range(len(queries))]
+            return self._select_rows(rows, over)
         with trace.span("repro.fleet.route"):
             routing = self.route(queries)
         results = [[] for _ in range(len(queries))]
+        over = np.zeros(len(queries), bool)
         ctrs = []
         with trace.span("repro.fleet.phase1"):
             for pi, part in enumerate(self.partitions):
@@ -350,13 +368,25 @@ class SpatialShards:
                 with trace.span("repro.fleet.readback"):
                     ids = np.asarray(ids)
                     counts = np.asarray(counts)
-                for qi, local_q in enumerate(hit):
-                    found = ids[qi, :counts[qi]]
-                    results[local_q].append(part.ids[found])
+                with trace.span("repro.fleet.ids"):
+                    over[hit] |= counts[:len(hit)] > result_cap
+                    for qi, local_q in enumerate(hit):
+                        found = ids[qi, :counts[qi]]
+                        results[local_q].append(part.ids[found])
         self._tally(ctrs)
         with trace.span("repro.fleet.merge"):
-            return [np.sort(np.concatenate(r)) if r else
+            rows = [np.sort(np.concatenate(r)) if r else
                     np.empty((0,), np.int64) for r in results]
+        return self._select_rows(rows, over)
+
+    @staticmethod
+    def _select_rows(rows: List[np.ndarray], over: np.ndarray) -> SelectRows:
+        """Count one select call's ids and cut-short rows; wrap its answer."""
+        trace.add("repro.fleet.result_ids", sum(len(r) for r in rows))
+        overflowed = np.nonzero(over)[0]
+        if len(overflowed):
+            trace.add("repro.fleet.overflowed_rows", len(overflowed))
+        return SelectRows(rows, overflowed)
 
     # ------------------------------------------------------------------
     # spatial join (probe rects × partitioned data)

@@ -66,7 +66,9 @@ operator the queue admits scores queries row-independently (asserted by
 the hypothesis schedule property in tests/test_spatial_shard.py and the
 chaos parity sweep in tests/test_chaos.py).  The batch-level ``overflow``
 flag is conservative — a request reports overflow if any request in its
-coalesced batch overflowed.
+coalesced batch overflowed.  A select request with a row that holds more
+ids than ``result_cap`` fails with ``ResultOverflow`` rather than resolve
+to a cut-short answer; the other requests of its batch resolve as usual.
 
 Spans (runtime/trace.py): the runner's ``repro.queue.gather`` (waiting for
 and coalescing requests), ``repro.queue.assemble`` (concatenate + pad) and
@@ -108,6 +110,11 @@ class QueueClosed(RuntimeError):
 
 class DeadlineExceeded(TimeoutError):
     """The request's deadline lapsed before a result was available."""
+
+
+class ResultOverflow(RuntimeError):
+    """A row of the request held more ids than the select's result cap: its
+    answer would be incomplete, so none is given."""
 
 
 @dataclasses.dataclass(eq=False)
@@ -539,7 +546,15 @@ class ServeQueue:
                 continue
             m = len(r.rows)
             if self.op == "select":
-                self._resolve(r, out[r.off:r.off + m])
+                cut = [i - r.off for i in getattr(out, "overflowed", ())
+                       if r.off <= i < r.off + m]
+                if cut:
+                    self._bump("overflowed_requests")
+                    self._resolve_exc(r, ResultOverflow(
+                        f"rows {cut} of the request hold more than "
+                        f"result_cap={self.result_cap} ids"))
+                else:
+                    self._resolve(r, out[r.off:r.off + m])
             else:
                 ids, d, ovf = out
                 self._resolve(r, (ids[r.off:r.off + m],
@@ -554,12 +569,13 @@ class ServeQueue:
         """Coalescing + robustness stats: dispatched batches, admitted
         requests/rows, mean rows per dispatch, straggler re-issues and
         engine failures (with per-shard rows from the backing ShardPool),
-        retry/deadline/degradation counts, and the health tracker's
+        retry/deadline/degradation counts, select requests failed for a
+        result cap (``overflowed_requests``), and the health tracker's
         quarantine/probe totals + current per-replica states."""
         with self._slock:
             s: Dict[str, Any] = dict(self.stats)
         for key in ("retries", "dispatch_failures", "deadline_exceeded",
-                    "degraded_dispatches"):
+                    "degraded_dispatches", "overflowed_requests"):
             s.setdefault(key, 0)
         pool = self.pool.stats()
         s["reissues"] = pool["reissues"]

@@ -555,3 +555,39 @@ def test_host_counters_equal_the_sum_of_the_engine_calls(shard_cache,
     assert shards.last_counters.asdict() == _field_sums(seen)
     assert all(isinstance(v, jax.Array)
                for v in shards.last_counters.tree_flatten()[0])
+
+
+def test_select_row_over_the_result_cap_fails_its_request_alone(
+        shard_cache):
+    """A window that holds more points than ``result_cap`` fails its
+    request with ``ResultOverflow`` and bumps
+    ``repro.fleet.overflowed_rows``; a sibling request of the same batch
+    still resolves exactly."""
+    from repro.launch.queue import ResultOverflow
+    from repro.runtime import trace
+    rects, shards = _queue_fleet(shard_cache)
+    cap = 64
+    big = np.array([[0.0, 0.0, 1.0, 1.0]], np.float32)
+    small = np.array([[0.40, 0.40, 0.45, 0.45]], np.float32)
+    assert 0 < len(brute_select(rects, small[0])) <= cap
+
+    direct = shards.range_select(np.concatenate([big, small]),
+                                 result_cap=cap)
+    np.testing.assert_array_equal(direct.overflowed, [0])
+    np.testing.assert_array_equal(direct[1], brute_select(rects, small[0]))
+
+    before = trace.snapshot()["counters"].get(
+        "repro.fleet.overflowed_rows", 0)
+    with ServeQueue(shards, "select", result_cap=cap, max_batch=2,
+                    max_delay_s=1.0) as q:
+        f_big, f_small = q.submit(big), q.submit(small)
+        with pytest.raises(ResultOverflow):
+            f_big.result(timeout=60)
+        got = f_small.result(timeout=60)
+        summary = q.summary
+    after = trace.snapshot()["counters"]["repro.fleet.overflowed_rows"]
+    assert summary["batches"] == 1 and summary["requests"] == 2
+    assert summary["overflowed_requests"] == 1
+    assert after - before == 1
+    assert len(got) == 1
+    np.testing.assert_array_equal(got[0], brute_select(rects, small[0]))

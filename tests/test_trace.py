@@ -337,3 +337,33 @@ def test_counters_sum_compiles_in_warm_and_never_again(fleet):
         made.add(_deltas(before, trace.snapshot(), "repro.fleet.knn")[0])
     assert min(made) == 1 and max(made) >= len(shards.partitions)
     assert counters._sum._cache_size() == 1
+
+
+def test_select_result_path_spans_and_counters(fleet, monkeypatch):
+    """On the host path ``repro.fleet.ids`` spans each partition call's
+    mapping to global ids, and ``repro.fleet.result_ids`` is added once
+    per fleet call, by the ids it returned."""
+    rects, shards = fleet
+    rng = np.random.default_rng(47)
+    lo = rng.random((6, 2)).astype(np.float32) * 0.8
+    rows = np.concatenate([lo, lo + np.float32(0.2)], axis=1)
+    adds = []
+    add = trace.add
+
+    def recording_add(name, n=1):
+        adds.append((name, n))
+        add(name, n)
+    monkeypatch.setattr(trace, "add", recording_add)
+    before = trace.snapshot()
+    got = [shards.range_select(rows), shards.range_select(rows[:1])]
+    after = trace.snapshot()
+    calls = [n for name, n in adds if name == "repro.fleet.partition_calls"]
+    spans = after["spans"]["repro.fleet.ids"]["count"] \
+        - before["spans"].get("repro.fleet.ids", {"count": 0})["count"]
+    assert len(calls) == spans > 2
+    ids = [n for name, n in adds if name == "repro.fleet.result_ids"]
+    assert ids == [sum(len(r) for r in g) for g in got]
+    assert ids[0] > 0
+    assert after["counters"]["repro.fleet.result_ids"] \
+        - before["counters"].get("repro.fleet.result_ids", 0) == sum(ids)
+    assert not any(name == "repro.fleet.overflowed_rows" for name, _ in adds)
