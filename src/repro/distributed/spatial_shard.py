@@ -36,9 +36,12 @@ Spans (runtime/trace.py): each public operator runs inside
 and ``repro.fleet.phase2`` (the partition loops) and ``repro.fleet.merge``
 (merging partition answers); every engine call is one
 ``repro.fleet.enqueue`` (bucket, host-to-device copy, the call until it
-returns) then one ``repro.fleet.readback`` (the blocking copies of its
-answer and overflow flag back to the host), and one
-``repro.fleet.partition_calls``.  ``range_select`` maps each call's local
+returns) and one ``repro.fleet.partition_calls``.  ``repro.fleet.readback``
+is the blocking copy of answers and overflow flags back to the host: once
+per call in ``range_select`` and ``join`` and in a distance operator's
+phase 2, once for all of a distance operator's phase-1 calls, which are
+launched before any is read back (``repro.fleet.pipelined_calls`` counts
+the calls of such a shared read-back of two or more).  ``range_select`` maps each call's local
 ids to global ids per row in ``repro.fleet.ids``, and counts the ids it
 returns in ``repro.fleet.result_ids`` and the rows a result cap cut short
 in ``repro.fleet.overflowed_rows``.  The calls' Counters are collected as
@@ -473,29 +476,46 @@ class SpatialShards:
     # distance operators (kNN / kNN-join / filtered kNN)
     # ------------------------------------------------------------------
 
-    def _run_partition(self, op: str, pi: int, queries: np.ndarray,
-                       k: int):
-        """Run one partition's batched distance engine; local → global ids.
+    def _launch(self, op: str, pi: int, queries: np.ndarray, k: int):
+        """Launch one partition's batched distance engine and start the
+        host copies of its answer and overflow flag; read nothing back.
 
         Query subsets ride power-of-two buckets (``_bucket``) so each
         partition only does work proportional to the queries actually
         routed to it (phase-1 subsets partition the batch; phase-2 subsets
-        are usually tiny).  Shared by every distance operator — the
-        padding/overflow subtleties live in one place.
+        are usually tiny).  Returns ``(pi, rows, device outputs, ctr)``
+        for ``_collect``.
         """
+        import jax
         import jax.numpy as jnp
-        part = self.partitions[pi]
-        b = len(queries)
         fn = self.engine_for(op, pi, k=k)
         with trace.span("repro.fleet.enqueue"):
             ids, dists, ctr = fn(jnp.asarray(self._bucket(queries)))
+            out = (ids, dists, ctr.overflow)
+            for x in out:          # a wrapper may hand back host values
+                if isinstance(x, jax.Array):
+                    x.copy_to_host_async()
         trace.add("repro.fleet.partition_calls")
+        return pi, len(queries), out, ctr
+
+    def _collect(self, launched) -> list:
+        """Read back every ``_launch``ed call in one blocking
+        ``jax.device_get``; per call → ``(global ids, float64 sq-dists,
+        overflow, ctr)``, in launch order.  Shared by every distance
+        operator — the padding/overflow subtleties live in one place."""
+        import jax
+        if len(launched) > 1:
+            trace.add("repro.fleet.pipelined_calls", len(launched))
         with trace.span("repro.fleet.readback"):
-            ids = np.asarray(ids)[:b]
+            host = jax.device_get([out for _, _, out, _ in launched])
+        results = []
+        for (pi, b, _, ctr), (ids, dists, ovf) in zip(launched, host):
+            ids = ids[:b]
             dists = np.asarray(dists, np.float64)[:b]
-            ovf = bool(ctr.overflow)
-        gids = np.where(ids >= 0, part.ids[np.maximum(ids, 0)], -1)
-        return gids, dists, ovf, ctr
+            gids = np.where(ids >= 0,
+                            self.partitions[pi].ids[np.maximum(ids, 0)], -1)
+            results.append((gids, dists, bool(ovf), ctr))
+        return results
 
     @trace.spanned("repro.fleet.knn")
     def knn(self, points: np.ndarray, k: int
@@ -574,13 +594,15 @@ class SpatialShards:
         overflow = False
         ctrs = []
         # ---- phase 1: primary partitions ----
+        # every primary call is launched before any is read back: the
+        # subsets partition the batch, so no call waits on another's answer
         with trace.span("repro.fleet.phase1"):
-            for pi in range(p):
-                sel = np.nonzero(primary == pi)[0]
-                if len(sel) == 0:
-                    continue
-                gids, dists, ovf, ctr = self._run_partition(
-                    op, pi, queries[sel], k)
+            sels = {int(pi): np.nonzero(primary == pi)[0]
+                    for pi in np.unique(primary)}
+            launched = [self._launch(op, pi, queries[sel], k)
+                        for pi, sel in sels.items()]
+            for sel, (gids, dists, ovf, ctr) in zip(
+                    sels.values(), self._collect(launched)):
                 ctrs.append(ctr)
                 cand_ids[sel], cand_d[sel] = gids, dists
                 overflow |= ovf
@@ -589,7 +611,8 @@ class SpatialShards:
         # ---- phase 2: secondary partitions within τ ----
         # τ slack: partition distances are f32 (jax) while the router matrix
         # is exact f64, so widen the bound a hair — only ever *adds* fan-out,
-        # never skips a partition that could hold a true k-th neighbor
+        # never skips a partition that could hold a true k-th neighbor.
+        # One call at a time: each tightens τ for the next one's routing.
         with trace.span("repro.fleet.phase2"):
             for pi in range(p):
                 tau_cmp = tau * (1.0 + 1e-5) + 1e-30
@@ -597,8 +620,8 @@ class SpatialShards:
                                  & (dmat[:, pi] <= tau_cmp))[0]
                 if len(sel) == 0:
                     continue
-                gids, dists, ovf, ctr = self._run_partition(
-                    op, pi, queries[sel], k)
+                (gids, dists, ovf, ctr), = self._collect(
+                    [self._launch(op, pi, queries[sel], k)])
                 ctrs.append(ctr)
                 overflow |= ovf
                 with trace.span("repro.fleet.merge"):

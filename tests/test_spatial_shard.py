@@ -591,3 +591,167 @@ def test_select_row_over_the_result_cap_fails_its_request_alone(
     assert after - before == 1
     assert len(got) == 1
     np.testing.assert_array_equal(got[0], brute_select(rects, small[0]))
+
+
+# ---------------------------------------------------------------------------
+# the host path's phase 1: every primary call launched before any read-back
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tied_fleet():
+    """Points left and right of x = 0.5 and 40 copies of (0.5, 0.5), which
+    the STR split at the median puts into tiles on both sides of a tile
+    boundary: their distances tie across partitions."""
+    rng = np.random.default_rng(57)
+    left = rng.random((2000, 2)) * [0.49, 1.0]
+    right = rng.random((2000, 2)) * [0.49, 1.0] + [0.51, 0.0]
+    pts = np.concatenate([left, np.full((40, 2), 0.5), right])
+    rects = np.concatenate([pts, pts], axis=1).astype(np.float32)
+    shards = SpatialShards.build(rects, n_partitions=4, fanout=16)
+    tied = [pi for pi, part in enumerate(shards.partitions)
+            if np.isin(np.arange(2000, 2040), part.ids).any()]
+    assert len(tied) >= 2
+    return rects, shards
+
+
+def _one_call_at_a_time(shards, op, rows, route, k):
+    """Two-phase routing with each partition call read back before the
+    next is launched: the host path as it was before phase 1 shared one
+    read-back.  → (global ids, float64 sq-dists)."""
+    import jax.numpy as jnp
+    dist = mindist_rect_matrix_np if op == "knn_join" else mindist_matrix_np
+    dmat = dist(route, shards.router_mbrs)
+    primary = np.argmin(dmat, axis=1)
+    ids = np.full((len(rows), k), -1, np.int64)
+    d = np.full((len(rows), k), np.inf)
+
+    def call(pi, sel):
+        got_i, got_d, ctr = shards.engine_for(op, pi, k=k)(
+            jnp.asarray(shards._bucket(rows[sel])))
+        got_i = np.asarray(got_i)[:len(sel)]
+        got_d = np.asarray(got_d, np.float64)[:len(sel)]
+        assert not bool(ctr.overflow)
+        part_ids = shards.partitions[pi].ids
+        return np.where(got_i >= 0, part_ids[np.maximum(got_i, 0)], -1), got_d
+
+    for pi in range(len(shards.partitions)):
+        sel = np.nonzero(primary == pi)[0]
+        if len(sel):
+            ids[sel], d[sel] = call(pi, sel)
+    tau = d[:, k - 1].copy()
+    for pi in range(len(shards.partitions)):
+        sel = np.nonzero((primary != pi)
+                         & (dmat[:, pi] <= tau * (1.0 + 1e-5) + 1e-30))[0]
+        if len(sel) == 0:
+            continue
+        got_i, got_d = call(pi, sel)
+        md = np.concatenate([d[sel], got_d], axis=1)
+        mi = np.concatenate([ids[sel], got_i], axis=1)
+        order = np.lexsort((mi, md))[:, :k]
+        d[sel] = np.take_along_axis(md, order, axis=1)
+        ids[sel] = np.take_along_axis(mi, order, axis=1)
+        tau[sel] = d[sel, k - 1]
+    return ids, d
+
+
+def _recording(shards, monkeypatch, seen):
+    """Wrap ``shards.engine_for`` so every engine call appends
+    ``(partition, query bytes, Counters)`` to ``seen``."""
+    engine_for = shards.engine_for
+
+    def get(op, pi, **kw):
+        fn = engine_for(op, pi, **kw)
+
+        def call(q):
+            out = fn(q)
+            seen.append((pi, np.asarray(q).tobytes(), out[-1]))
+            return out
+        return call
+    monkeypatch.setattr(shards, "engine_for", get)
+
+
+def _counted(before, after, name):
+    return after["counters"].get(name, 0) - before["counters"].get(name, 0)
+
+
+@pytest.mark.parametrize("data", ["edges", "ties"])
+@pytest.mark.parametrize("op", ["knn", "knn_join", "knn_filtered"])
+def test_pipelined_phase1_matches_one_call_at_a_time(shard_cache, tied_fleet,
+                                                     monkeypatch, op, data):
+    """The host path launches every phase-1 call before reading any back.
+    Its ids and float32 distances are bit for bit those of reading each
+    call back before the next, and it makes the same partition calls with
+    the same rows, in the same order; phase 2 runs here (points on tile
+    edges, or distance ties across a tile boundary)."""
+    from repro.runtime import trace
+    rng = np.random.default_rng(61)
+    if data == "edges":
+        _, shards = _queue_fleet(shard_cache)
+        m = shards.router_mbrs
+        edges = np.stack([m[:, 2], (m[:, 1] + m[:, 3]) / 2], axis=1)
+        pts = np.concatenate([rng.random((8, 2)), edges])
+    else:
+        _, shards = tied_fleet
+        pts = np.concatenate([np.full((3, 2), 0.5), rng.random((6, 2)),
+                              0.5 + 0.003 * rng.standard_normal((6, 2))])
+    rows, route = _distance_rows(op, pts.astype(np.float32))
+    seen = []
+    _recording(shards, monkeypatch, seen)
+    before = trace.snapshot()
+    ids, d, ovf = getattr(shards, op)(rows, 4)
+    after = trace.snapshot()
+    got = [(pi, q) for pi, q, _ in seen]
+    seen.clear()
+    want_ids, want_d = _one_call_at_a_time(shards, op, rows, route, 4)
+    assert got == [(pi, q) for pi, q, _ in seen]
+    assert _counted(before, after, "repro.fleet.partition_calls") == len(got)
+    dist = mindist_rect_matrix_np if op == "knn_join" else mindist_matrix_np
+    primaries = len(np.unique(np.argmin(dist(route, shards.router_mbrs),
+                                        axis=1)))
+    assert primaries >= 2 and len(got) > primaries
+    assert _counted(before, after, "repro.fleet.pipelined_calls") \
+        == primaries
+    assert not ovf
+    np.testing.assert_array_equal(ids, want_ids)
+    np.testing.assert_array_equal(d, want_d)
+    np.testing.assert_array_equal(d.astype(np.float32),
+                                  want_d.astype(np.float32))
+
+
+def test_tight_tier_overflow_through_pipelined_phase1(monkeypatch):
+    """Tight kNN tiers of one frontier slot a level overflow; each engine
+    re-runs its full tier inside its own call, so the pipelined phase 1
+    still returns the full tier's answer (the unpatched fleet's, whose
+    tight tier holds), and a wrapper on ``engine_for`` sees exactly
+    ``partition_calls`` calls, each with its overflow flag."""
+    from repro.core import knn_vector
+    from repro.runtime import trace
+    rng = np.random.default_rng(59)
+    rects = uniform_rects(rng, 3000)
+    pts = rng.random((24, 2)).astype(np.float32)
+    want = SpatialShards.build(rects, n_partitions=4, fanout=16).knn(pts, 1)
+
+    real = knn_vector.knn_frontier_caps
+
+    def one_slot(tree, k, *a, policy="static", **kw):
+        full = real(tree, k, *a, **kw)
+        return (1,) * len(full) if policy == "adaptive" else full
+    monkeypatch.setattr(knn_vector, "knn_frontier_caps", one_slot)
+    shards = SpatialShards.build(rects, n_partitions=4, fanout=16)
+    seen = []
+    _recording(shards, monkeypatch, seen)
+    before = trace.snapshot()
+    ids, d, ovf = shards.knn(pts, 1)
+    after = trace.snapshot()
+    assert len(seen) == _counted(before, after,
+                                 "repro.fleet.partition_calls")
+    primaries = len(np.unique(np.argmin(
+        mindist_matrix_np(pts, shards.router_mbrs), axis=1)))
+    assert primaries >= 2
+    assert _counted(before, after, "repro.fleet.pipelined_calls") \
+        == primaries
+    assert [bool(c.overflow) for _, _, c in seen] == [False] * len(seen)
+    assert all(int(c.escalations) == 1 for _, _, c in seen[:primaries])
+    assert not ovf and not want[2]
+    np.testing.assert_array_equal(ids, want[0])
+    np.testing.assert_array_equal(d, want[1])

@@ -245,15 +245,19 @@ def test_queued_batch_spans_share_its_id_and_count_partition_calls(fleet):
     dispatched = [b for b, rs in sorted(batches.items())
                   if any(r.name == "repro.fleet.knn" for r in rs)]
     assert len(dispatched) == len(reqs)
-    calls = 0
+    calls = pipelined = 0
     for bid, rows in zip(dispatched, reqs):
         names = collections.Counter(r.name for r in batches[bid])
         assert FLEET_SPANS | {"repro.queue.gather", "repro.queue.assemble",
                               "repro.queue.resolve"} <= set(names)
         want = _implied_calls(rects, shards, rows)
+        phase1 = sum(1 for phase, _ in want if phase == 1)
         assert names["repro.fleet.enqueue"] == len(want)
-        assert names["repro.fleet.readback"] == len(want)
+        # one read-back for all phase-1 calls, then one per phase-2 call
+        assert names["repro.fleet.readback"] \
+            == (phase1 > 0) + len(want) - phase1
         calls += len(want)
+        pipelined += phase1 if phase1 >= 2 else 0
         by_id = {r.span: r for r in batches[bid]}
         for r in batches[bid]:
             if r.parent is not None:
@@ -265,7 +269,10 @@ def test_queued_batch_spans_share_its_id_and_count_partition_calls(fleet):
                     <= parent.end - parent.start
     counted = after["counters"]["repro.fleet.partition_calls"] \
         - before["counters"].get("repro.fleet.partition_calls", 0)
-    assert counted == calls
+    assert counted == calls and pipelined > 0
+    assert after["counters"].get("repro.fleet.pipelined_calls", 0) \
+        - before["counters"].get("repro.fleet.pipelined_calls", 0) \
+        == pipelined
     waits = after["times"]["repro.queue.wait"]
     assert waits["count"] - before["times"].get(
         "repro.queue.wait", {"count": 0})["count"] == len(reqs)
