@@ -20,7 +20,7 @@ def main(argv=None):
                     help="tiny sizes (CI smoke)")
     ap.add_argument("--only", default=None,
                     help="comma list: select,sweeps,join,knn,knn-join,"
-                         "fused,quant,caps,browse,service,lm")
+                         "fused,quant,caps,browse,service")
     ap.add_argument("--out-dir", default="runs/bench")
     args = ap.parse_args(argv)
 
@@ -117,10 +117,6 @@ def main(argv=None):
         print(f"[spatial service sharded: host fan-out vs mesh SPMD]  "
               f"n={n_service // 4}")
         all_rows.append(bench_service.run_sharded(n=n_service // 4))
-    if want("lm"):
-        from . import bench_lm
-        print("[lm steps]")
-        all_rows.append(bench_lm.run())
 
     for rows in all_rows:
         path = os.path.join(args.out_dir, rows.name + ".csv")

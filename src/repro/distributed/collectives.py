@@ -1,34 +1,15 @@
-"""Cross-shard collectives for the mesh-sharded query engine, plus the
-roofline collective term (HLO parsing).
+"""Cross-shard collectives for the mesh-sharded query engine.
 
-Two halves:
-
-1. **Executable collectives** — the deterministic cross-shard merge
-   primitives the ``shard_map`` traversal programs run
-   (``core/traversal.make_mesh_engine``): an all-gather along the partition
-   axis, a (distance, id)-lexicographic top-k merge (the τ merge of the
-   two-phase kNN/kNN-join), and the partition/shard ``Counters`` folds that
-   keep ``dispatches`` at O(levels) rather than O(partitions × levels).
-
-2. **Roofline accounting** — parse the post-SPMD HLO for collective ops and
-   sum their operand bytes (``cost_analysis()`` does not expose collective
-   traffic, so we read ``compiled.as_text()`` and account every all-gather /
-   all-reduce / reduce-scatter / all-to-all / collective-permute).
-
-Bytes accounted per op (per device, per step):
-  all-gather        — output_bytes − input_bytes (data received)
-  all-reduce        — 2 × input_bytes × (n−1)/n  (ring: RS + AG phases)
-  reduce-scatter    — input_bytes × (n−1)/n
-  all-to-all        — input_bytes × (n−1)/n
-  collective-permute— input_bytes
-
-where n = replica-group size parsed from the op's ``replica_groups``.
+The deterministic cross-shard merge primitives the ``shard_map`` traversal
+programs run (``core/traversal.make_mesh_engine``): an all-gather along the
+partition axis, a (distance, id)-lexicographic top-k merge (the τ merge of
+the two-phase kNN/kNN-join), and the partition/shard ``Counters`` folds that
+keep ``dispatches`` at O(levels) rather than O(partitions × levels).
 """
 from __future__ import annotations
 
 import dataclasses
-import re
-from typing import Dict, List, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -100,88 +81,3 @@ def psum_counters(ctr: Counters, axis_name: str) -> Counters:
                        if f.name in _SUM_MAX_FIELDS
                        else jax.lax.psum(v, axis_name))
     return Counters(**out)
-
-_DTYPE_BYTES = {
-    "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
-    "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
-    "c64": 8, "c128": 16,
-}
-
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-                "collective-permute")
-
-
-def _shape_bytes(shape_str: str) -> int:
-    """'f32[8,128]' → bytes.  Tuple shapes: sum of components."""
-    total = 0
-    for m in _SHAPE_RE.finditer(shape_str):
-        dt, dims = m.group(1), m.group(2)
-        if dt not in _DTYPE_BYTES:
-            continue
-        n = 1
-        if dims:
-            for d in dims.split(","):
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dt]
-    return total
-
-
-def _group_size(line: str) -> int:
-    m = re.search(r"replica_groups=\{\{([^}]*)\}", line)
-    if m:
-        return len(m.group(1).split(","))
-    m = re.search(r"replica_groups=\[(\d+),(\d+)\]", line)
-    if m:  # iota v2 format [groups, size]
-        return int(m.group(2))
-    return 2
-
-
-@dataclasses.dataclass
-class CollectiveStats:
-    bytes_by_kind: Dict[str, int]
-    count_by_kind: Dict[str, int]
-    ops: List[Tuple[str, int, int]]          # (kind, bytes, group_size)
-
-    @property
-    def total_bytes(self) -> int:
-        return sum(self.bytes_by_kind.values())
-
-
-def parse_collectives(hlo_text: str) -> CollectiveStats:
-    bytes_by: Dict[str, int] = {}
-    count_by: Dict[str, int] = {}
-    ops: List[Tuple[str, int, int]] = []
-    for line in hlo_text.splitlines():
-        ls = line.strip()
-        m = re.match(r"(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\([^)]*\)|\S+)\s+"
-                     r"(all-gather|all-reduce|reduce-scatter|all-to-all|"
-                     r"collective-permute)(?:-start|-done)?\(", ls)
-        if not m:
-            continue
-        if "-done(" in ls:        # async pair: count the -start only
-            continue
-        out_shape, kind = m.group(1), m.group(2)
-        out_bytes = _shape_bytes(out_shape)
-        # operand shapes: everything inside the call parens
-        call = ls[m.end():]
-        in_bytes = _shape_bytes(call)
-        n = _group_size(ls)
-        if kind == "all-gather":
-            moved = max(out_bytes - in_bytes, 0)
-        elif kind == "all-reduce":
-            moved = int(2 * in_bytes * (n - 1) / max(n, 1))
-        elif kind in ("reduce-scatter", "all-to-all"):
-            moved = int(in_bytes * (n - 1) / max(n, 1))
-        else:
-            moved = in_bytes
-        bytes_by[kind] = bytes_by.get(kind, 0) + moved
-        count_by[kind] = count_by.get(kind, 0) + 1
-        ops.append((kind, moved, n))
-    return CollectiveStats(bytes_by, count_by, ops)
-
-
-def collective_seconds(stats: CollectiveStats, link_bw: float = 50e9,
-                       links_per_chip: int = 1) -> float:
-    """Lower-bound wire time: bytes moved per chip / per-chip ICI bw."""
-    return stats.total_bytes / (link_bw * links_per_chip)

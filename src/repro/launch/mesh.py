@@ -1,24 +1,14 @@
-"""Production mesh construction.
+"""Device meshes for the spatial query service.
 
-(16, 16) single-pod = 256 chips; (2, 16, 16) multi-pod = 512 chips across
-2 pods.  ``pod`` is the slow inter-pod axis (DCN/ICI-wrapped), ``data`` is
-intra-pod DP, ``model`` is the TP/EP axis.  A FUNCTION (not a module-level
-constant) so importing this module never touches jax device state.
+``spatial_mesh`` lays the local devices out as the partition axis
+(``model``) of the mesh-sharded engine, or as a ``(data, model)`` grid of
+replicas; ``replica_meshes`` splits the devices into one 1-D mesh per
+replica.  FUNCTIONS (not module-level constants) so importing this module
+never touches jax device state.
 """
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / examples, e.g. (2, 4) on 8 host devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
 
 
 def spatial_mesh(n_devices=None, replicas: int = 1):

@@ -15,8 +15,7 @@ re-issue, runtime/straggler.py), spatial join, kNN and kNN-join (two-phase
 asserted on every spatial serve run and by tests/test_serve_modes.py), so
 the served surface can never silently lag the operator family.  ``--dryrun``
 shrinks every size for the CI smoke that instantiates each registered spec
-end-to-end.  ``--mode lm`` drives the LM decode path (reduced config) as a
-batched token service — both serving styles share the launcher.
+end-to-end.
 
 ``--queue`` switches the queueable operators to async continuous batching
 (launch/queue.ServeQueue): ``--clients`` concurrent closed-loop clients
@@ -535,7 +534,7 @@ RUNNERS = {
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="spatial",
-                    choices=sorted(MODE_TO_SPEC) + ["lm"])
+                    choices=sorted(MODE_TO_SPEC))
     ap.add_argument("--k", type=int, default=8,
                     help="neighbors per query/batch (knn / knn-join / "
                          "browse modes)")
@@ -619,8 +618,6 @@ def main(argv=None):
 
     from . import compile_cache
     compile_cache.enable()
-    if args.mode == "lm":
-        return _serve_lm(args)
     spec = traversal.get_spec(MODE_TO_SPEC[args.mode])
     missing = set(traversal.spec_names()) - set(RUNNERS)
     assert not missing, f"registered specs without a serve runner: {missing}"
@@ -631,28 +628,6 @@ def main(argv=None):
         print(f"--queue: {spec.name} does not coalesce (session/query-less "
               f"operator); serving synchronously")
     return RUNNERS[spec.name](args, spec)
-
-
-def _serve_lm(args):
-    import jax
-    import jax.numpy as jnp
-    from repro.configs import registry
-    from repro.models.model import Model
-    from repro.serve.serve_step import generate
-
-    cfg = registry.reduced_config(registry.get("tinyllama-1.1b"))
-    model = Model(cfg)
-    params = model.init_params(jax.random.PRNGKey(args.seed))
-    rng = np.random.default_rng(args.seed)
-    toks = rng.integers(0, cfg.vocab, (args.batch_size, 32),
-                        dtype=np.int32)
-    t0 = time.time()
-    out = generate(model, params, {"tokens": jnp.asarray(toks)}, n_new=16)
-    dt = time.time() - t0
-    tps = args.batch_size * 16 / dt
-    print(f"LM decode service: {args.batch_size} seqs × 16 new tokens in "
-          f"{dt:.2f}s → {tps:,.0f} tok/s; sample: {np.asarray(out[0])[:8]}")
-    return {"tok_per_s": tps}
 
 
 if __name__ == "__main__":

@@ -33,11 +33,6 @@ global batches — ``kill:r1@5`` kills replica 1 on its own 6th dispatch
 regardless of how round-robin interleaved the fleet.  Randomized clauses
 (flaky/spike) draw from ``random.Random((seed, clause, replica, n))``, so
 the outcome at any dispatch is independent of thread interleaving.
-
-``FailurePlan`` in runtime/fault_tolerance.py (the training-side step-
-indexed crash schedule) is now a thin wrapper over a ``FaultPlan`` of
-``crash`` clauses — one schedule engine for both serving and training
-fault injection.
 """
 from __future__ import annotations
 
@@ -122,13 +117,6 @@ class FaultPlan:
         if not clauses:
             raise ValueError(f"empty fault spec {spec!r}")
         return cls(clauses, seed=seed)
-
-    @classmethod
-    def crash_at_steps(cls, steps: Sequence[int],
-                       replica: int = 0) -> "FaultPlan":
-        """The training-side schedule shape: crash once at each given step
-        index (FailurePlan's contract, now expressed as crash clauses)."""
-        return cls(tuple(FaultClause("crash", replica, at=s) for s in steps))
 
     def __str__(self) -> str:
         return ",".join(str(c) for c in self.clauses)

@@ -6,9 +6,7 @@ to its home shard; if the deadline lapses — or the shard *raises* — re-issue
 to a hot-spare replica and take whichever answer lands first.  A raised
 shard exception is a re-issue trigger exactly like a missed deadline (the
 ``failures`` stat counts them); the pool only propagates an error once every
-engine that could serve the payload has failed.  (Training-side straggler
-handling is different — checkpoint/restart + synchronous steps — and lives
-in fault_tolerance.py.)
+engine that could serve the payload has failed.
 
 The executor here is host-side and backend-agnostic: ``shards`` are
 callables (in production: per-replica dispatch handles built by

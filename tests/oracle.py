@@ -1,12 +1,14 @@
 """Differential-oracle harness for the operator family.
 
-One helper — ``assert_matches_oracle(op, layouts, backends, seeds, fused)``
-— runs any operator cell (physical layout × kernel backend × data seed ×
-fused) against its brute-force numpy oracle, so every new operator / layout
-/ backend cell is verified the same way: build a random instance, run the
+One check — ``OracleMatrix.check(layout, backend, fused)`` — runs any
+operator cell (physical layout × kernel backend × data seed × fused)
+against its brute-force numpy oracle, so every new operator / layout /
+backend cell is verified the same way: build a random instance, run the
 vectorized cell, compare exactly (select/join id sets) or to distance
 tolerance with id-at-reported-distance verification (kNN / kNN-join), and
-assert no overflow was flagged.
+assert no overflow was flagged.  ``oracle_cells`` lists a matrix's runnable
+cells, so a test can give each cell its own parametrised case;
+``assert_matches_oracle`` runs a whole matrix in one test.
 
 A second axis — ``assert_sharded_parity(op, seeds)`` — verifies the
 distributed dispatcher the same way: the host-orchestrated partition
@@ -310,62 +312,99 @@ OPS = {
 }
 
 
+def oracle_cells(op: str, layouts=LAYOUTS, backends=(None,),
+                 fused=(False,)):
+    """The runnable (layout, backend, fused) cells of ``op``'s matrix, in
+    harness order.  ``backends`` entries are None (layout-specific jnp
+    math) or kernel backends ('xla' / 'pallas_interpret'); kernel cells
+    only exist where ``KERNEL_CELLS`` says the operator implements them,
+    and fused cells only exist on kernel backends."""
+    kernel_cells = KERNEL_CELLS[op]
+    cells = [(layout, backend, fu) for layout, backend, fu
+             in itertools.product(layouts, backends, fused)
+             if (backend is None and not fu)
+             or (backend is not None and (layout, fu) in kernel_cells)]
+    assert cells, \
+        f"no runnable cells for {op}: {layouts} × {backends} × {fused}"
+    return cells
+
+
+def cell_id(cell) -> str:
+    """pytest id of one oracle cell, e.g. ``d1-xla-fused``."""
+    layout, backend, fu = cell
+    return "-".join([layout, backend or "jnp"] + (["fused"] if fu else []))
+
+
+class OracleMatrix:
+    """One seed's instance of operator ``op`` checked cell by cell against
+    its brute-force oracle.  Cell results are memoised, so the parametrised
+    cases of one matrix (a module-scoped fixture) build the instance once,
+    and a D3 cell compares against the D1 result its matrix already holds.
+
+    ``cells`` is the matrix (``oracle_cells``).  Every cell validates its
+    dispatch tally against the operator spec's stage model; a D3 cell whose
+    matrix holds the D1 cell of the same (backend, fused) is asserted
+    bit-exact against it; with ``engine_parity`` every cell also re-runs
+    through the generic engine entry point (traversal.build) and must match
+    the wrapper bit-for-bit.  ``params`` tune the instance (n, fanout,
+    batch, k, ...)."""
+
+    def __init__(self, op: str, seed: int, cells, engine_parity=True,
+                 **params):
+        self.op, self.seed, self.cells = op, seed, list(cells)
+        self.spec = OPS[op]
+        self.engine_parity = engine_parity
+        self.inst = self.spec.make(seed, **params)
+        self._results = {}
+
+    def result(self, layout, backend, fused):
+        key = (layout, backend, fused)
+        if key not in self._results:
+            self._results[key] = self.spec.run(self.inst, layout, backend,
+                                               fused=fused)
+        return self._results[key]
+
+    def check(self, layout, backend, fused):
+        spec, inst = self.spec, self.inst
+        ctx = f"{self.op} layout={layout} backend={backend} " \
+              f"seed={self.seed} fused={fused}"
+        result = self.result(layout, backend, fused)
+        spec.check(inst, result, ctx)
+        result[-1].validate_dispatches(
+            traversal.get_spec(spec.spec_name).stage_model,
+            spec.height(inst), fused=fused)
+        # D3's conservative quantized prune may only over-approximate
+        # frontiers; after the exact leaf re-check its *emitted* results
+        # must be bit-identical to the D1 cell of the same (backend,
+        # fused) — counters legitimately differ (less pruning), so only
+        # the result leaves are compared.
+        if layout == "d3" and ("d1", backend, fused) in self.cells:
+            _assert_bitwise_equal(
+                result[:-1], self.result("d1", backend, fused)[:-1],
+                f"d3-vs-d1 bit-exactness: {ctx}")
+        if self.engine_parity:
+            args, kwargs = spec.engine_args(inst, layout, backend, fused)
+            eng = traversal.build(spec.spec_name, *args, **kwargs)
+            qs = inst.get("queries")
+            eng_result = eng(jnp.asarray(qs)) if qs is not None else eng()
+            _assert_bitwise_equal(result, eng_result,
+                                  f"engine-entry parity: {ctx}")
+
+
 def assert_matches_oracle(op: str, layouts=LAYOUTS, backends=(None,),
                           seeds=(0,), fused=(False,), **params):
     """Run operator ``op`` over the (layout × backend × seed × fused) matrix
-    against its brute-force oracle.  ``backends`` entries are None
-    (layout-specific jnp math) or kernel backends ('xla' /
-    'pallas_interpret'); kernel cells only exist where ``KERNEL_CELLS``
-    says the operator implements them and are skipped elsewhere, and fused
-    cells only exist on kernel backends.
-    ``params`` tune the instance (n, fanout, batch, k, ...).  Every cell
-    validates its dispatch tally against the operator spec's stage model;
-    the first seed's cells additionally re-run through the generic engine
-    entry point (traversal.build) and must match the wrapper bit-for-bit.
-    Returns the number of cells actually verified (callers may assert
-    coverage)."""
-    spec = OPS[op]
-    op_spec = traversal.get_spec(spec.spec_name)
-    kernel_cells = KERNEL_CELLS[op]
-    cells = 0
+    against its brute-force oracle (``oracle_cells`` × ``seeds``, each cell
+    checked by ``OracleMatrix.check``); only the first seed's cells run the
+    engine-entry parity check.  Returns the number of cells actually
+    verified (callers may assert coverage)."""
+    cells = oracle_cells(op, layouts, backends, fused)
     for si, seed in enumerate(seeds):
-        inst = spec.make(seed, **params)
-        d1_results = {}
-        for layout, backend, fu in itertools.product(layouts, backends,
-                                                     fused):
-            if backend is not None and (layout, fu) not in kernel_cells:
-                continue
-            if fu and backend is None:
-                continue
-            ctx = f"{op} layout={layout} backend={backend} seed={seed} " \
-                  f"fused={fu}"
-            result = spec.run(inst, layout, backend, fused=fu)
-            spec.check(inst, result, ctx)
-            result[-1].validate_dispatches(op_spec.stage_model,
-                                           spec.height(inst), fused=fu)
-            # D3's conservative quantized prune may only over-approximate
-            # frontiers; after the exact leaf re-check its *emitted*
-            # results must be bit-identical to the D1 cell of the same
-            # (backend, fused) — counters legitimately differ (less
-            # pruning), so only the result leaves are compared.
-            if layout == "d1":
-                d1_results[(backend, fu)] = result
-            elif layout == "d3" and (backend, fu) in d1_results:
-                _assert_bitwise_equal(
-                    result[:-1], d1_results[(backend, fu)][:-1],
-                    f"d3-vs-d1 bit-exactness: {ctx}")
-            if si == 0:
-                args, kwargs = spec.engine_args(inst, layout, backend, fu)
-                eng = traversal.build(spec.spec_name, *args, **kwargs)
-                qs = inst.get("queries")
-                eng_result = eng(jnp.asarray(qs)) if qs is not None \
-                    else eng()
-                _assert_bitwise_equal(result, eng_result,
-                                      f"engine-entry parity: {ctx}")
-            cells += 1
-    assert cells > 0, \
-        f"no runnable cells for {op}: {layouts} × {backends} × {fused}"
-    return cells
+        matrix = OracleMatrix(op, seed, cells, engine_parity=si == 0,
+                              **params)
+        for cell in cells:
+            matrix.check(*cell)
+    return len(cells) * len(seeds)
 
 
 # --------------------------------------------------------------------------

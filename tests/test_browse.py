@@ -11,6 +11,7 @@ import pytest
 
 from repro.core import knn_browse, knn_vector, rtree, traversal
 from repro.core.knn_browse import BROWSE_SPEC
+from repro.core.layouts import layout_names
 
 from conftest import uniform_rects
 
@@ -35,24 +36,28 @@ def _browse_all(tree, pts, kb, steps, **kwargs):
     return np.concatenate(ids, axis=1), np.concatenate(ds, axis=1), cur
 
 
-def test_prefix_consistency_spans_descents(tree_and_points):
+@pytest.fixture(scope="module")
+def browsed_120(tree_and_points):
+    tree, _, pts = tree_and_points
+    return _browse_all(tree, pts, 4, steps=30)          # 120 neighbors
+
+
+@pytest.mark.parametrize("k", [1, 3, 4, 11, 40, 120])
+def test_prefix_consistency_spans_descents(tree_and_points, browsed_120, k):
     """Concatenated browse batches equal fixed-k kNN for every prefix —
     including prefixes deep enough that the session had to re-activate
     deferred subtrees (multi-descent resume)."""
     tree, rects, pts = tree_and_points
-    kb = 4
-    ids, d, cur = _browse_all(tree, pts, kb, steps=30)   # 120 neighbors
+    ids, d, cur = browsed_120
     assert int(cur.state.descents) > 1, \
         "test too shallow: the resume path never ran"
     assert not cur.overflow.any()
-    for k in (1, 3, 4, 11, 40, 120):
-        fi, fd, fc = knn_vector.make_knn_bfs(tree, k=k)(jnp.asarray(pts))
-        assert int(fc.overflow) == 0
-        np.testing.assert_array_equal(d[:, :k], np.asarray(fd))
-        diff = ids[:, :k] != np.asarray(fi)
-        if diff.any():                          # ids may differ only at ties
-            np.testing.assert_array_equal(d[:, :k][diff],
-                                          np.asarray(fd)[diff])
+    fi, fd, fc = knn_vector.make_knn_bfs(tree, k=k)(jnp.asarray(pts))
+    assert int(fc.overflow) == 0
+    np.testing.assert_array_equal(d[:, :k], np.asarray(fd))
+    diff = ids[:, :k] != np.asarray(fi)
+    if diff.any():                              # ids may differ only at ties
+        np.testing.assert_array_equal(d[:, :k][diff], np.asarray(fd)[diff])
 
 
 def test_emission_is_globally_sorted_and_distinct(tree_and_points):
@@ -125,17 +130,24 @@ def test_tiny_pool_flags_overflow_not_silent_loss(tree_and_points):
     assert int(cur.counters.overflow) == 1
 
 
-def test_backend_and_layout_cells_agree(tree_and_points):
+@pytest.fixture(scope="module")
+def browsed_base(tree_and_points):
     tree, _, pts = tree_and_points
-    base_i, base_d, _ = _browse_all(tree, pts, 4, steps=5)
-    from repro.core.layouts import layout_names
-    layout_cells = [dict(layout=lo) for lo in layout_names() if lo != "d1"]
-    for kwargs in (*layout_cells, dict(backend="xla"),
-                   dict(backend="pallas_interpret")):
-        ids, d, cur = _browse_all(tree, pts, 4, steps=5, **kwargs)
-        assert not cur.overflow.any()
-        np.testing.assert_allclose(d, base_d, rtol=1e-6, atol=1e-12,
-                                   err_msg=str(kwargs))
+    return _browse_all(tree, pts, 4, steps=5)
+
+
+@pytest.mark.parametrize("kwargs", [
+    *(dict(layout=lo) for lo in layout_names() if lo != "d1"),
+    dict(backend="xla"), dict(backend="pallas_interpret")],
+    ids=lambda kw: "-".join(f"{k}={v}" for k, v in kw.items()))
+def test_backend_and_layout_cells_agree(tree_and_points, browsed_base,
+                                        kwargs):
+    tree, _, pts = tree_and_points
+    base_i, base_d, _ = browsed_base
+    ids, d, cur = _browse_all(tree, pts, 4, steps=5, **kwargs)
+    assert not cur.overflow.any()
+    np.testing.assert_allclose(d, base_d, rtol=1e-6, atol=1e-12,
+                               err_msg=str(kwargs))
 
 
 def test_browse_registered_and_generic_entry(tree_and_points):

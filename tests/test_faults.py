@@ -1,11 +1,9 @@
 """Seeded fault injection (runtime/faults.py): spec grammar round-trips,
 clause semantics (kill / crash / slow / flaky / spike), determinism of the
-seeded draws under any interleaving, the injector's dispatch accounting,
-and the FailurePlan unification with the training-side crash schedule."""
+seeded draws under any interleaving, and the injector's dispatch
+accounting."""
 import pytest
 
-from repro.runtime import faults
-from repro.runtime.fault_tolerance import FailurePlan
 from repro.runtime.faults import (FaultClause, FaultInjector, FaultPlan,
                                   InjectedFault, ReplicaDead, parse_clause)
 
@@ -134,24 +132,3 @@ def test_injector_underlying_fn_not_called_on_injection():
     assert calls == []             # the fault pre-empts the engine
     assert fn("y") == "y"          # crash recovers after its one dispatch
     assert calls == ["y"]
-
-
-# ---------------------------------------------------------------------------
-# FailurePlan unification (training-side crash schedule over FaultPlan)
-# ---------------------------------------------------------------------------
-
-def test_failure_plan_delegates_to_fault_plan():
-    fp = FailurePlan(fail_at=(2, 5))
-    fired = []
-    for step in range(8):
-        try:
-            fp.maybe_fail(step)
-        except RuntimeError as e:
-            assert "injected failure" in str(e)
-            fired.append(step)
-    assert fired == [2, 5]
-    # a restarted loop revisits the crashed step without re-firing
-    fp.maybe_fail(2)
-    fp.maybe_fail(5)
-    assert isinstance(fp._plan, faults.FaultPlan)
-    assert all(c.kind == "crash" for c in fp._plan.clauses)

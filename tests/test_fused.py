@@ -16,7 +16,7 @@ from repro.core import (join_vector, knn_join_vector, knn_vector, rtree,
                         select_vector)
 
 from conftest import uniform_rects
-from oracle import KERNEL_BACKENDS, assert_matches_oracle
+from oracle import KERNEL_BACKENDS, OracleMatrix, cell_id, oracle_cells
 
 COUNTERS_EXCEPT_DISPATCHES = (
     "nodes_visited", "predicates", "vector_ops", "enqueued", "pruned_outer",
@@ -47,15 +47,26 @@ def tree_and_queries():
 # differential-oracle matrix: fused cells on both kernel backends
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("op", ["select", "knn", "knn_join"])
-def test_fused_matches_oracle(op):
-    # the fused D3 variant exists for select only (KERNEL_CELLS) — the
-    # harness skips the unsupported d3 fused cells for knn / knn_join
-    cells = assert_matches_oracle(op, layouts=("d1", "d3"),
-                                  backends=KERNEL_BACKENDS, seeds=(11,),
-                                  fused=(True,))
+# the fused D3 variant exists for select only (KERNEL_CELLS) — the
+# harness skips the unsupported d3 fused cells for knn / knn_join
+FUSED_OPS = ("select", "knn", "knn_join")
+FUSED_CELLS = {op: oracle_cells(op, ("d1", "d3"), backends=KERNEL_BACKENDS,
+                                fused=(True,))
+               for op in FUSED_OPS}
+
+
+@pytest.fixture(scope="module")
+def fused_matrices():
+    return {op: OracleMatrix(op, 11, FUSED_CELLS[op]) for op in FUSED_OPS}
+
+
+@pytest.mark.parametrize("op,cell", [
+    pytest.param(op, cell, id=f"{op}-{cell_id(cell)}")
+    for op in FUSED_OPS for cell in FUSED_CELLS[op]])
+def test_fused_matches_oracle(fused_matrices, op, cell):
     expect = 2 if op == "select" else 1
-    assert cells == expect * len(KERNEL_BACKENDS)
+    assert len(FUSED_CELLS[op]) == expect * len(KERNEL_BACKENDS)
+    fused_matrices[op].check(*cell)
 
 
 # ---------------------------------------------------------------------------
@@ -114,7 +125,8 @@ def test_knn_fused_parity_beam_overflow(tree_and_queries, backend):
 
 
 @pytest.mark.parametrize("backend", KERNEL_BACKENDS)
-def test_knn_fused_parity_root_leaf(backend):
+@pytest.mark.parametrize("k", [3, 20])
+def test_knn_fused_parity_root_leaf(backend, k):
     """Height-1 tree (the root is the leaf) and k > n: the fused leaf kernel
     alone answers the query, padding missing neighbours as (-1, inf)."""
     rng = np.random.default_rng(43)
@@ -122,12 +134,11 @@ def test_knn_fused_parity_root_leaf(backend):
     tree = rtree.build_rtree(rects, fanout=16)
     assert tree.height == 1
     q = jnp.asarray(rng.random((5, 2)).astype(np.float32))
-    for k in (3, 20):
-        i0, d0, _ = knn_vector.make_knn_bfs(tree, k=k, backend="xla")(q)
-        i1, d1, _ = knn_vector.make_knn_bfs(tree, k=k, backend=backend,
-                                            fused=True)(q)
-        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-        np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
+    i0, d0, _ = knn_vector.make_knn_bfs(tree, k=k, backend="xla")(q)
+    i1, d1, _ = knn_vector.make_knn_bfs(tree, k=k, backend=backend,
+                                        fused=True)(q)
+    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+    np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
 
 
 @pytest.mark.parametrize("backend", KERNEL_BACKENDS)

@@ -7,7 +7,8 @@ import pytest
 from repro.core import join_scalar, join_vector, rtree
 
 from conftest import brute_join, uniform_rects
-from oracle import KERNEL_BACKENDS, LAYOUTS, assert_matches_oracle
+from oracle import (KERNEL_BACKENDS, LAYOUTS, OracleMatrix, cell_id,
+                    oracle_cells)
 
 
 @pytest.fixture(scope="module")
@@ -60,13 +61,22 @@ def test_vector_join_variants(trees, kw):
     assert not bool(ctr.overflow)
 
 
-def test_join_matches_oracle_harness():
-    """The plain layout × backend matrix via the shared differential
-    harness (optimization-flag variants stay in VARIANTS above)."""
-    assert_matches_oracle("join", layouts=LAYOUTS, backends=(None,),
-                          seeds=(11,))
-    assert_matches_oracle("join", layouts=("d1",),
-                          backends=KERNEL_BACKENDS, seeds=(11,))
+# the plain layouts on jnp math, then D1 on each kernel backend
+JOIN_CELLS = (oracle_cells("join", LAYOUTS, backends=(None,))
+              + oracle_cells("join", ("d1",), backends=KERNEL_BACKENDS))
+
+
+@pytest.fixture(scope="module")
+def join_matrix():
+    return OracleMatrix("join", 11, JOIN_CELLS)
+
+
+@pytest.mark.parametrize("cell", JOIN_CELLS, ids=cell_id)
+def test_join_matches_oracle_harness(join_matrix, cell):
+    """One cell of the plain layout × backend matrix via the shared
+    differential harness (optimization-flag variants stay in VARIANTS
+    above)."""
+    join_matrix.check(*cell)
 
 
 def test_o3_o4_reduce_predicates(trees):
@@ -105,18 +115,19 @@ def test_self_join(trees):
     assert all((i, i) in got for i in range(len(ra)))
 
 
-def test_different_heights():
+@pytest.mark.parametrize("tall_outer", [True, False])
+def test_different_heights(tall_outer):
     rng = np.random.default_rng(14)
     ra = uniform_rects(rng, 4000, eps=0.01)      # height 3 @ fanout 16
     rb = uniform_rects(rng, 100, eps=0.02)       # height 2
     ta = rtree.build_rtree(ra, fanout=16, sort_key="lx")
     tb = rtree.build_rtree(rb, fanout=16, sort_key="lx")
-    for o, i in ((ta, tb), (tb, ta)):
-        jn = join_vector.make_join_bfs(o, i, result_cap=1 << 17, o3=True)
-        pairs, n, _ = jn()
-        got = set(map(tuple, np.asarray(pairs[:int(n)])))
-        ref = brute_join(np.asarray(o.rects), np.asarray(i.rects))
-        assert got == ref
+    o, i = (ta, tb) if tall_outer else (tb, ta)
+    jn = join_vector.make_join_bfs(o, i, result_cap=1 << 17, o3=True)
+    pairs, n, _ = jn()
+    got = set(map(tuple, np.asarray(pairs[:int(n)])))
+    ref = brute_join(np.asarray(o.rects), np.asarray(i.rects))
+    assert got == ref
 
 # the hypothesis property sweep lives in test_properties.py (skipped when
 # hypothesis is not installed, so plain tests here always collect)

@@ -11,7 +11,8 @@ from repro.core.geometry import (brute_force_knn, mindist, mindist_matrix_np,
 from repro.distributed.spatial_shard import SpatialShards
 
 from conftest import uniform_rects
-from oracle import KERNEL_BACKENDS, LAYOUTS, assert_matches_oracle
+from oracle import (KERNEL_BACKENDS, LAYOUTS, OracleMatrix,
+                    assert_matches_oracle, cell_id, oracle_cells)
 
 
 def _true_sq_dist(rects, p, ids):
@@ -71,18 +72,18 @@ def tree_and_rects():
     return rtree.build_rtree(rects, fanout=64), rects
 
 
-def test_scalar_best_first(tree_and_rects):
+@pytest.mark.parametrize("k", [1, 8, 64])
+def test_scalar_best_first(tree_and_rects, k):
     t, rects = tree_and_rects
     rng = np.random.default_rng(31)
     pts = rng.random((6, 2)).astype(np.float32)
-    for k in (1, 8, 64):
-        oids, od = brute_force_knn(rects, pts, k)
-        for i, p in enumerate(pts):
-            ids, d, ctr = knn_scalar.knn_best_first(t, p, k)
-            np.testing.assert_allclose(d, od[i], rtol=1e-5, atol=1e-9)
-            assert ctr.nodes_visited > 0
-            # best-first opens a tiny fraction of the tree
-            assert ctr.nodes_visited < t.n_nodes_total()
+    oids, od = brute_force_knn(rects, pts, k)
+    for i, p in enumerate(pts):
+        ids, d, ctr = knn_scalar.knn_best_first(t, p, k)
+        np.testing.assert_allclose(d, od[i], rtol=1e-5, atol=1e-9)
+        assert ctr.nodes_visited > 0
+        # best-first opens a tiny fraction of the tree
+        assert ctr.nodes_visited < t.n_nodes_total()
 
 
 # ---------------------------------------------------------------------------
@@ -108,32 +109,40 @@ def test_vector_counters_show_pruning(tree_and_rects):
     assert int(ctr.nodes_visited) < 4 * t.n_nodes_total()
 
 
-@pytest.mark.parametrize("backend", KERNEL_BACKENDS)
-def test_kernel_backend_matches_oracle(backend):
-    assert_matches_oracle("knn", layouts=("d1", "d3"), backends=(backend,),
-                          seeds=(34,), k=8)
+KNN_KERNEL_CELLS = oracle_cells("knn", ("d1", "d3"),
+                                backends=KERNEL_BACKENDS)
+
+
+@pytest.fixture(scope="module")
+def knn_kernel_matrix():
+    return OracleMatrix("knn", 34, KNN_KERNEL_CELLS, k=8)
+
+
+@pytest.mark.parametrize("cell", KNN_KERNEL_CELLS, ids=cell_id)
+def test_kernel_backend_matches_oracle(knn_kernel_matrix, cell):
+    knn_kernel_matrix.check(*cell)
 
 
 # ---------------------------------------------------------------------------
 # edge cases
 # ---------------------------------------------------------------------------
 
-def test_ties_duplicate_points():
+@pytest.mark.parametrize("k", [3, 7])          # k cuts through tie groups
+def test_ties_duplicate_points(k):
     rng = np.random.default_rng(35)
     base = rng.random((40, 2)).astype(np.float32)
     pts = np.repeat(base, 5, axis=0)            # every point 5×
     rects = np.concatenate([pts, pts], axis=1)
     t = rtree.build_rtree(rects, fanout=16)
     q = rng.random((4, 2)).astype(np.float32)
-    for k in (3, 7):                            # k cuts through tie groups
-        _, od = brute_force_knn(rects, q, k)
-        fn = knn_vector.make_knn_bfs(t, k=k)
-        ids, d, _ = fn(jnp.asarray(q))
-        np.testing.assert_allclose(np.sort(np.asarray(d), axis=1),
-                                   np.sort(od, axis=1), rtol=1e-5)
-        for i in range(len(q)):
-            sids, sd, _ = knn_scalar.knn_best_first(t, q[i], k)
-            np.testing.assert_allclose(sd, od[i], rtol=1e-5)
+    _, od = brute_force_knn(rects, q, k)
+    fn = knn_vector.make_knn_bfs(t, k=k)
+    ids, d, _ = fn(jnp.asarray(q))
+    np.testing.assert_allclose(np.sort(np.asarray(d), axis=1),
+                               np.sort(od, axis=1), rtol=1e-5)
+    for i in range(len(q)):
+        sids, sd, _ = knn_scalar.knn_best_first(t, q[i], k)
+        np.testing.assert_allclose(sd, od[i], rtol=1e-5)
 
 
 def test_k_exceeds_n_rects():
@@ -151,23 +160,35 @@ def test_k_exceeds_n_rects():
     np.testing.assert_allclose(np.sort(d[0, :7]), np.sort(sd[:7]), rtol=1e-5)
 
 
+LANE_COUNT_SIZES = (52, 200)
+
+
+def _lane_count_instance(n):
+    """The (rects, points) of size ``n``, drawn in LANE_COUNT_SIZES order
+    from one stream, so each size sees the same data whichever case runs."""
+    rng = np.random.default_rng(23)
+    for m in LANE_COUNT_SIZES:
+        rects = uniform_rects(rng, m, eps=0.01)
+        pts = rng.random((4, 2)).astype(np.float32)
+        if m == n:
+            return rects, pts
+    raise KeyError(n)
+
+
+@pytest.mark.parametrize("n", LANE_COUNT_SIZES)
 @pytest.mark.parametrize("sort_key", [None, "lx"])
-def test_k_exceeds_lane_count(sort_key):
+def test_k_exceeds_lane_count(sort_key, n):
     # k > fanout: upper levels have fewer than k lanes, so the τ bound must
     # not tighten there (regression: truncated k-th MINMAXDIST guaranteed
     # only C·F objects and silently pruned true neighbors)
-    rng = np.random.default_rng(23)
-    for n in (52, 200):
-        rects = uniform_rects(rng, n, eps=0.01)
-        t = rtree.build_rtree(rects, fanout=4, sort_key=sort_key)
-        pts = rng.random((4, 2)).astype(np.float32)
-        fn = knn_vector.make_knn_bfs(t, k=32)
-        ids, d, ctr = fn(jnp.asarray(pts))
-        assert not bool(ctr.overflow)
-        _, od = brute_force_knn(rects, pts, 32)
-        np.testing.assert_allclose(np.sort(np.asarray(d), axis=1),
-                                   np.sort(od, axis=1), rtol=1e-4,
-                                   atol=1e-9)
+    rects, pts = _lane_count_instance(n)
+    t = rtree.build_rtree(rects, fanout=4, sort_key=sort_key)
+    fn = knn_vector.make_knn_bfs(t, k=32)
+    ids, d, ctr = fn(jnp.asarray(pts))
+    assert not bool(ctr.overflow)
+    _, od = brute_force_knn(rects, pts, 32)
+    np.testing.assert_allclose(np.sort(np.asarray(d), axis=1),
+                               np.sort(od, axis=1), rtol=1e-4, atol=1e-9)
 
 
 def test_single_node_tree():
@@ -185,23 +206,29 @@ def test_single_node_tree():
 # sharded ≡ single tree
 # ---------------------------------------------------------------------------
 
-def test_sharded_matches_single_tree():
+@pytest.fixture(scope="module")
+def sharded_fleet():
     rng = np.random.default_rng(37)
     rects = uniform_rects(rng, 20_000, eps=0.003)
     t = rtree.build_rtree(rects, fanout=32)
     shards = SpatialShards.build(rects, n_partitions=6, fanout=32)
-    assert len(shards.partitions) >= 2
     q = rng.random((10, 2)).astype(np.float32)
-    for k in (1, 8):
-        gids, gd, ovf = shards.knn(q, k)
-        assert not ovf
-        fn = knn_vector.make_knn_bfs(t, k=k)
-        _, d, _ = fn(jnp.asarray(q))
-        np.testing.assert_allclose(np.sort(gd, axis=1),
-                                   np.sort(np.asarray(d), axis=1), rtol=1e-4)
-        _, od = brute_force_knn(rects, q, k)
-        np.testing.assert_allclose(np.sort(gd, axis=1), np.sort(od, axis=1),
-                                   rtol=1e-4)
-        for i, p in enumerate(q):
-            np.testing.assert_allclose(_true_sq_dist(rects, p, gids[i]),
-                                       gd[i], rtol=1e-4, atol=1e-9)
+    return rects, t, shards, q
+
+
+@pytest.mark.parametrize("k", [1, 8])
+def test_sharded_matches_single_tree(sharded_fleet, k):
+    rects, t, shards, q = sharded_fleet
+    assert len(shards.partitions) >= 2
+    gids, gd, ovf = shards.knn(q, k)
+    assert not ovf
+    fn = knn_vector.make_knn_bfs(t, k=k)
+    _, d, _ = fn(jnp.asarray(q))
+    np.testing.assert_allclose(np.sort(gd, axis=1),
+                               np.sort(np.asarray(d), axis=1), rtol=1e-4)
+    _, od = brute_force_knn(rects, q, k)
+    np.testing.assert_allclose(np.sort(gd, axis=1), np.sort(od, axis=1),
+                               rtol=1e-4)
+    for i, p in enumerate(q):
+        np.testing.assert_allclose(_true_sq_dist(rects, p, gids[i]),
+                                   gd[i], rtol=1e-4, atol=1e-9)

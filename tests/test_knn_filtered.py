@@ -7,14 +7,27 @@ import pytest
 from repro.core import knn_filtered, knn_vector, rtree
 
 from conftest import uniform_rects
-from oracle import LAYOUTS, assert_matches_oracle
+from oracle import LAYOUTS, OracleMatrix, cell_id, oracle_cells
+
+# kernel backends are not implemented for the filtered spec (jnp-only
+# window masks), so the matrix is layouts × seeds
+FILTERED_CELLS = oracle_cells("knn_filtered")
+FILTERED_SEEDS = (0, 1)
 
 
-def test_filtered_matches_oracle_layouts():
-    # kernel backends are not implemented for the filtered spec (jnp-only
-    # window masks), so the matrix is layouts × seeds
-    assert assert_matches_oracle("knn_filtered", seeds=(0, 1)) == \
-        len(LAYOUTS) * 2
+@pytest.fixture(scope="module")
+def filtered_matrices():
+    # the first seed also checks the generic engine entry point
+    return {seed: OracleMatrix("knn_filtered", seed, FILTERED_CELLS,
+                               engine_parity=seed == FILTERED_SEEDS[0])
+            for seed in FILTERED_SEEDS}
+
+
+@pytest.mark.parametrize("seed", FILTERED_SEEDS)
+@pytest.mark.parametrize("cell", FILTERED_CELLS, ids=cell_id)
+def test_filtered_matches_oracle_layouts(filtered_matrices, cell, seed):
+    assert len(FILTERED_CELLS) * len(FILTERED_SEEDS) == len(LAYOUTS) * 2
+    filtered_matrices[seed].check(*cell)
 
 
 def test_full_window_reduces_to_plain_knn():

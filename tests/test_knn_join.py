@@ -14,7 +14,8 @@ from repro.core.geometry import (brute_force_knn_join, mindist,
 from repro.distributed.spatial_shard import SpatialShards
 
 from conftest import uniform_rects
-from oracle import KERNEL_BACKENDS, LAYOUTS, assert_matches_oracle
+from oracle import (KERNEL_BACKENDS, LAYOUTS, OracleMatrix,
+                    assert_matches_oracle, cell_id, oracle_cells)
 
 
 def _true_sq_dist(rects, q, ids):
@@ -93,16 +94,32 @@ def test_minmaxdist_rect_reduces_to_point_form():
 # pallas_interpret} via the shared differential harness
 # ---------------------------------------------------------------------------
 
+LAYOUT_SEEDS = (40, 41)
+
+
+@pytest.mark.parametrize("seed", LAYOUT_SEEDS)
 @pytest.mark.parametrize("layout", LAYOUTS)
-def test_knn_join_matches_oracle_layouts(layout):
-    assert_matches_oracle("knn_join", layouts=(layout,), backends=(None,),
-                          seeds=(40, 41), k=8)
+def test_knn_join_matches_oracle_layouts(layout, seed):
+    # a one-layout matrix per seed; the first seed also checks the
+    # generic engine entry point
+    cell = (layout, None, False)
+    OracleMatrix("knn_join", seed, [cell],
+                 engine_parity=seed == LAYOUT_SEEDS[0], k=8).check(*cell)
 
 
-@pytest.mark.parametrize("backend", KERNEL_BACKENDS)
-def test_knn_join_matches_oracle_kernel_backends(backend):
-    assert_matches_oracle("knn_join", layouts=("d1", "d3"),
-                          backends=(backend,), seeds=(42,), k=8)
+KNN_JOIN_KERNEL_CELLS = oracle_cells("knn_join", ("d1", "d3"),
+                                     backends=KERNEL_BACKENDS)
+
+
+@pytest.fixture(scope="module")
+def knn_join_kernel_matrix():
+    return OracleMatrix("knn_join", 42, KNN_JOIN_KERNEL_CELLS, k=8)
+
+
+@pytest.mark.parametrize("cell", KNN_JOIN_KERNEL_CELLS, ids=cell_id)
+def test_knn_join_matches_oracle_kernel_backends(knn_join_kernel_matrix,
+                                                 cell):
+    knn_join_kernel_matrix.check(*cell)
 
 
 @pytest.mark.parametrize("k", [1, 64])
@@ -134,17 +151,17 @@ def tree_and_rects():
     return rtree.build_rtree(rects, fanout=32), rects
 
 
-def test_scalar_knn_join_best_first(tree_and_rects):
+@pytest.mark.parametrize("k", [1, 8])
+def test_scalar_knn_join_best_first(tree_and_rects, k):
     t, rects = tree_and_rects
     rng = np.random.default_rng(51)
     outer = uniform_rects(rng, 5, eps=0.01)
-    for k in (1, 8):
-        oids, od = brute_force_knn_join(outer, rects, k)
-        ids, d, ctr = knn_join_scalar.knn_join_best_first(t, outer, k)
-        np.testing.assert_allclose(d, od, rtol=1e-5, atol=1e-12)
-        assert ctr.nodes_visited > 0
-        # best-first opens a tiny fraction of the tree per query
-        assert ctr.nodes_visited < len(outer) * t.n_nodes_total()
+    oids, od = brute_force_knn_join(outer, rects, k)
+    ids, d, ctr = knn_join_scalar.knn_join_best_first(t, outer, k)
+    np.testing.assert_allclose(d, od, rtol=1e-5, atol=1e-12)
+    assert ctr.nodes_visited > 0
+    # best-first opens a tiny fraction of the tree per query
+    assert ctr.nodes_visited < len(outer) * t.n_nodes_total()
 
 
 # ---------------------------------------------------------------------------
@@ -243,25 +260,31 @@ def test_overlapping_outer_rect_zero_distances():
 # sharded two-phase ≡ single tree ≡ oracle
 # ---------------------------------------------------------------------------
 
-def test_sharded_knn_join_matches_single_tree():
+@pytest.fixture(scope="module")
+def sharded_fleet():
     rng = np.random.default_rng(57)
     rects = uniform_rects(rng, 12_000, eps=0.003)
     t = rtree.build_rtree(rects, fanout=32)
     shards = SpatialShards.build(rects, n_partitions=6, fanout=32)
-    assert len(shards.partitions) >= 2
     outer = uniform_rects(rng, 9, eps=0.01)
-    for k in (1, 8):
-        gids, gd, ovf = shards.knn_join(outer, k)
-        assert not ovf
-        fn = knn_join_vector.make_knn_join_bfs(t, k=k)
-        _, d, _ = fn(jnp.asarray(outer))
-        np.testing.assert_allclose(np.sort(gd, axis=1),
-                                   np.sort(np.asarray(d), axis=1),
-                                   rtol=1e-4)
-        _, od = brute_force_knn_join(outer, rects, k)
-        np.testing.assert_allclose(np.sort(gd, axis=1), np.sort(od, axis=1),
-                                   rtol=1e-4)
-        for i in range(len(outer)):
-            np.testing.assert_allclose(
-                _true_sq_dist(rects, outer[i], gids[i]), gd[i], rtol=1e-4,
-                atol=1e-9)
+    return rects, t, shards, outer
+
+
+@pytest.mark.parametrize("k", [1, 8])
+def test_sharded_knn_join_matches_single_tree(sharded_fleet, k):
+    rects, t, shards, outer = sharded_fleet
+    assert len(shards.partitions) >= 2
+    gids, gd, ovf = shards.knn_join(outer, k)
+    assert not ovf
+    fn = knn_join_vector.make_knn_join_bfs(t, k=k)
+    _, d, _ = fn(jnp.asarray(outer))
+    np.testing.assert_allclose(np.sort(gd, axis=1),
+                               np.sort(np.asarray(d), axis=1),
+                               rtol=1e-4)
+    _, od = brute_force_knn_join(outer, rects, k)
+    np.testing.assert_allclose(np.sort(gd, axis=1), np.sort(od, axis=1),
+                               rtol=1e-4)
+    for i in range(len(outer)):
+        np.testing.assert_allclose(
+            _true_sq_dist(rects, outer[i], gids[i]), gd[i], rtol=1e-4,
+            atol=1e-9)

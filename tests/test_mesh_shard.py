@@ -159,47 +159,49 @@ def test_partition_count_not_multiple_of_devices():
     np.testing.assert_array_equal(hd, md)
 
 
-def test_knn_edges_k_exceeds_partitions_and_b1():
-    """k beyond the partition (even the dataset) size: phase-1 τ stays inf,
-    phase 2 fans out everywhere, the merge pads with (-1, +inf) — exactly
-    like the host path.  Also the B=1 batch."""
+@pytest.fixture(scope="module")
+def tiny_fleets():
     rng = np.random.default_rng(23)
     rects = uniform_rects(rng, 40, eps=0.002)
     qs = rng.random((3, 2)).astype(np.float32)
-    host = _shards_for(rects, 4, 8, mesh=False)
-    meshed = _shards_for(rects, 4, 8)
-    for k in (1, 16, 64):
-        hi, hd, _ = host.knn(qs, k)
-        mi, md, _ = meshed.knn(qs, k)
-        np.testing.assert_array_equal(hi, mi)
-        np.testing.assert_array_equal(hd, md)
-    hi, hd, _ = host.knn(qs[:1], 4)
-    mi, md, _ = meshed.knn(qs[:1], 4)
+    return (qs, _shards_for(rects, 4, 8, mesh=False),
+            _shards_for(rects, 4, 8))
+
+
+@pytest.mark.parametrize("rows,k", [(3, 1), (3, 16), (3, 64), (1, 4)],
+                         ids=["k1", "k16", "k64", "b1"])
+def test_knn_edges_k_exceeds_partitions_and_b1(tiny_fleets, rows, k):
+    """k beyond the partition (even the dataset) size: phase-1 τ stays inf,
+    phase 2 fans out everywhere, the merge pads with (-1, +inf) — exactly
+    like the host path.  Also the B=1 batch."""
+    qs, host, meshed = tiny_fleets
+    hi, hd, _ = host.knn(qs[:rows], k)
+    mi, md, _ = meshed.knn(qs[:rows], k)
     np.testing.assert_array_equal(hi, mi)
     np.testing.assert_array_equal(hd, md)
 
 
-def test_warm_covers_every_registered_operator():
+@pytest.mark.parametrize("mesh", [False, None], ids=["host", "mesh"])
+def test_warm_covers_every_registered_operator(mesh):
     """The registry-keyed warmup accepts every spec (select/join included —
     the operators that historically had no warm path)."""
     rng = np.random.default_rng(19)
     rects = uniform_rects(rng, 2000, eps=0.002)
     lo = rng.random((32, 2)).astype(np.float32) * 0.9
     probe = np.concatenate([lo, lo + 0.01], axis=1).astype(np.float32)
-    for mesh in (False, None):
-        shards = _shards_for(rects, 2, 16, mesh=mesh)
-        for op in traversal.spec_names():
-            kw = dict(k=4) if traversal.get_spec(op).kind == "distance" \
-                or op == "browse" else {}
-            if op == "join":
-                kw = dict(probe=probe, result_cap=1 << 14)
-            if op == "browse" and not shards.mesh_enabled:
-                # distributed browsing refuses to silently flip the object
-                # onto the mesh path
-                with pytest.raises(RuntimeError):
-                    shards.warm(op, batch=8, **kw)
-                continue
-            shards.warm(op, batch=8, **kw)
-        # the historical spellings still work
-        shards.warm_knn(8, 4)
-        shards.warm_knn_join(8, 4)
+    shards = _shards_for(rects, 2, 16, mesh=mesh)
+    for op in traversal.spec_names():
+        kw = dict(k=4) if traversal.get_spec(op).kind == "distance" \
+            or op == "browse" else {}
+        if op == "join":
+            kw = dict(probe=probe, result_cap=1 << 14)
+        if op == "browse" and not shards.mesh_enabled:
+            # distributed browsing refuses to silently flip the object
+            # onto the mesh path
+            with pytest.raises(RuntimeError):
+                shards.warm(op, batch=8, **kw)
+            continue
+        shards.warm(op, batch=8, **kw)
+    # the historical spellings still work
+    shards.warm_knn(8, 4)
+    shards.warm_knn_join(8, 4)

@@ -7,14 +7,21 @@ from repro.core import flat as flatmod
 from repro.core import rtree, select_scalar, select_vector
 
 from conftest import brute_select, uniform_rects
-from oracle import LAYOUTS, assert_matches_oracle
+from oracle import LAYOUTS, OracleMatrix, cell_id, oracle_cells
+
+SELECT_CELLS = oracle_cells("select", LAYOUTS, backends=(None, "xla"))
 
 
-def test_select_matches_oracle_harness():
-    """The layout × backend matrix via the shared differential harness
-    (tests/oracle.py)."""
-    assert_matches_oracle("select", layouts=LAYOUTS,
-                          backends=(None, "xla"), seeds=(5,))
+@pytest.fixture(scope="module")
+def select_matrix():
+    return OracleMatrix("select", 5, SELECT_CELLS)
+
+
+@pytest.mark.parametrize("cell", SELECT_CELLS, ids=cell_id)
+def test_select_matches_oracle_harness(select_matrix, cell):
+    """One cell of the layout × backend matrix via the shared differential
+    harness (tests/oracle.py)."""
+    select_matrix.check(*cell)
 
 
 def _queries(rng, b, side):

@@ -302,20 +302,20 @@ def test_counters_validate_dispatches():
         Counters(dispatches=3).validate_dispatches(sm, 3, fused=False)
 
 
-def test_engine_charges_spec_stage_model():
+@pytest.mark.parametrize("fused,backend", [(False, None), (True, "xla")])
+def test_engine_charges_spec_stage_model(fused, backend):
     """An under- (or over-) counting operator cannot pass: the engine's
     tally is derived from the spec the operator registered."""
     import jax.numpy as jnp
     rng = np.random.default_rng(5)
     tree = rtree.build_rtree(uniform_rects(rng, 2000, eps=0.003), fanout=16)
     q = jnp.asarray(rng.random((3, 2)).astype(np.float32))
-    for fused, backend in ((False, None), (True, "xla")):
-        fn = traversal.build("knn", tree, k=5, backend=backend, fused=fused)
-        _, _, ctr = fn(q)
-        spec = traversal.get_spec("knn")
-        ctr.validate_dispatches(spec.stage_model, tree.height, fused=fused)
-        wrong = StageModel(inner=spec.stage_model.inner + 1,
-                           leaf=spec.stage_model.leaf,
-                           fused=(spec.stage_model.fused or 0) + 1)
-        with pytest.raises(AssertionError):
-            ctr.validate_dispatches(wrong, tree.height, fused=fused)
+    fn = traversal.build("knn", tree, k=5, backend=backend, fused=fused)
+    _, _, ctr = fn(q)
+    spec = traversal.get_spec("knn")
+    ctr.validate_dispatches(spec.stage_model, tree.height, fused=fused)
+    wrong = StageModel(inner=spec.stage_model.inner + 1,
+                       leaf=spec.stage_model.leaf,
+                       fused=(spec.stage_model.fused or 0) + 1)
+    with pytest.raises(AssertionError):
+        ctr.validate_dispatches(wrong, tree.height, fused=fused)
